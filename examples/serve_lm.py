@@ -39,11 +39,15 @@ bit-identical to the single-device run.
         --kill-replica-at 8
     PYTHONPATH=src python examples/serve_lm.py --trace trace.json
     PYTHONPATH=src python examples/serve_lm.py --mesh tp=2
-    PYTHONPATH=src python examples/serve_lm.py --arch mixtral-8x7b \\
+    PYTHONPATH=src python examples/serve_lm.py --arch mixtral-8x7b-reduced \\
         --mesh tp=2,ep=4
+
+``--arch`` takes any registry name: a ``-reduced`` config runs on the CPU,
+a published-width one needs a chip.
 """
 import argparse
 import json
+import sys
 import time
 
 import jax
@@ -58,7 +62,7 @@ from repro.serve.scheduler import StreamRequest
 
 def main():
     ap = argparse.ArgumentParser()
-    ap.add_argument("--arch", default="gemma2-2b")
+    ap.add_argument("--arch", default="gemma2-2b-reduced")
     ap.add_argument("--requests", type=int, default=12)
     ap.add_argument("--rows", type=int, default=4)
     ap.add_argument("--max-new", type=int, default=24)
@@ -75,7 +79,7 @@ def main():
                     help="speculative draft depth per round (0 disables; "
                          "default: plan rule — on at batch 1 where the "
                          "weight stream dominates). Needs an all-global-"
-                         "attention arch (e.g. --arch qwen2.5-3b) on fp "
+                         "attention arch (e.g. --arch qwen2.5-3b-reduced) on fp "
                          "pages; greedy outputs stay bit-identical")
     ap.add_argument("--ttl", type=float, default=None,
                     help="per-request deadline in decode steps from arrival "
@@ -91,7 +95,7 @@ def main():
                     help="serve mesh-sharded (ISSUE 10): tp shards "
                          "attention KV heads over per-device page pools, "
                          "ep shards the MoE expert axis (needs an MoE arch "
-                         "e.g. --arch mixtral-8x7b). Token streams stay "
+                         "e.g. --arch mixtral-8x7b-reduced). Token streams stay "
                          "bit-identical to single-device")
     ap.add_argument("--trace", metavar="OUT.json", default=None,
                     help="write the step-clock trace as Chrome trace_event "
@@ -101,7 +105,7 @@ def main():
         ap.error("--kill-replica-at needs --replicas > 1 (killing the "
                  "only replica just respawns it)")
 
-    cfg = get_config(args.arch + "-reduced")
+    cfg = get_config(args.arch)
     params = tfm.init_params(jax.random.PRNGKey(0), cfg)
 
     # resolve every dispatch decision once: pool provisioned for ~half-slot
@@ -228,7 +232,10 @@ def main():
             json.dump(tel.tracer.to_chrome_trace(), f)
         print(f"wrote {len(tel.tracer.events)} spans to {args.trace} "
               f"(open at https://ui.perfetto.dev)")
+    # the guard turns faults into outcomes; a run with a failed request
+    # must not report success
+    return 1 if st["outcomes"].get("failed") else 0
 
 
 if __name__ == "__main__":
-    main()
+    sys.exit(main())

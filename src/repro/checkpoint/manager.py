@@ -109,6 +109,9 @@ class CheckpointManager:
         for (p, leaf), shd in zip(paths, shard_leaves):
             key = _SEP.join(_entry_name(e) for e in p)
             arr = arrays[key]
+            # npz stores bfloat16 as opaque 2-byte records; the manifest
+            # keeps the real dtype
+            arr = arr.view(jax.numpy.dtype(manifest["dtypes"][key]))
             assert tuple(arr.shape) == tuple(leaf.shape), (key, arr.shape,
                                                            leaf.shape)
             if shd is not None:

@@ -23,11 +23,53 @@ import dataclasses
 import re
 from typing import Dict, List, Optional
 
-# ----------------------------------------------------------- TPU v5e constants
-PEAK_FLOPS = 197e12          # bf16 FLOP/s per chip
-HBM_BW = 819e9               # bytes/s per chip
-ICI_BW = 50e9                # bytes/s per link (spec: ~50 GB/s/link)
-HBM_CAP = 16e9               # bytes per chip
+# ------------------------------------------------------------- chip peaks
+@dataclasses.dataclass(frozen=True)
+class ChipPeaks:
+    """Published per-chip peaks of one accelerator kind."""
+    bf16_flops: float        # FLOP/s
+    int8_ops: float          # OP/s
+    hbm_bw: float            # bytes/s
+    hbm_bytes: float         # bytes
+    ici_link_bw: float       # bytes/s per chip-to-chip link
+    source: str
+
+
+# Keyed by JAX's ``device_kind``. A kind missing here is an error on the
+# chip path, never a silent default.
+CHIP_PEAKS: Dict[str, ChipPeaks] = {
+    "TPU v5 lite": ChipPeaks(
+        bf16_flops=197e12, int8_ops=393e12, hbm_bw=819e9, hbm_bytes=16e9,
+        ici_link_bw=50e9,
+        source="Google Cloud documentation, 'TPU v5e': 197 TFLOP/s bf16, "
+               "393 TOP/s int8, 16 GB HBM at 819 GB/s, 1,600 Gbit/s ICI "
+               "over 4 links"),
+}
+
+# The chip the analytic planners (dry-run, Eyexam rooflines) model.
+TARGET_KIND = "TPU v5 lite"
+PEAK_FLOPS = CHIP_PEAKS[TARGET_KIND].bf16_flops
+HBM_BW = CHIP_PEAKS[TARGET_KIND].hbm_bw
+ICI_BW = CHIP_PEAKS[TARGET_KIND].ici_link_bw
+HBM_CAP = CHIP_PEAKS[TARGET_KIND].hbm_bytes
+
+
+def device_peaks(device=None) -> ChipPeaks:
+    """Peaks of ``device`` (default: JAX's first device). A TPU whose kind
+    is not in :data:`CHIP_PEAKS` raises; the CPU backend, which runs the
+    kernels interpreted for tests, plans against the target chip."""
+    if device is None:
+        import jax
+        device = jax.devices()[0]
+    if device.platform != "tpu":
+        return CHIP_PEAKS[TARGET_KIND]
+    try:
+        return CHIP_PEAKS[device.device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no published peaks for device_kind {device.device_kind!r}; "
+            "add its entry, with its source, to core.eyexam.CHIP_PEAKS"
+        ) from None
 
 _DTYPE_BYTES = {
     "pred": 1, "s4": 1, "u4": 1, "s8": 1, "u8": 1, "f8e4m3fn": 1,

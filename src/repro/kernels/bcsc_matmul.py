@@ -30,7 +30,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.sparsity import BCSCMatrix
-from repro.kernels import epilogue as _epi
 from repro.kernels.epilogue import fused_epilogue
 
 
@@ -116,7 +115,7 @@ def bcsc_matmul_raw(x, blocks, row_ids, col_ids, *, n_out: int, bm: int,
         _bcsc_kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((M, n_out), out_dtype),
-        compiler_params=_epi.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(row_ids, col_ids, x, blocks)
@@ -194,7 +193,7 @@ def bcsc_gemv_raw(x, blocks, row_ids, col_ids, *, n_out: int, bm: int,
                           activation=activation, has_bias=has_bias),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((bm, n_out), out_dtype),
-        compiler_params=_epi.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(*args)

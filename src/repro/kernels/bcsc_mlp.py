@@ -67,7 +67,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import dataflow
-from repro.kernels import epilogue as _epi
 from repro.kernels.epilogue import fused_epilogue
 
 
@@ -319,7 +318,7 @@ def bcsc_mlp_raw(x, g_blocks, g_rows, g_cols, d_blocks, d_rows, d_cols,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((M, n_out), out_dtype),
-        compiler_params=_epi.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=semantics),
         interpret=interpret,
     )(counts, *args, *tensor_args)

@@ -14,14 +14,8 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.pallas import tpu as pltpu
 
 ACTIVATIONS = (None, "none", "relu", "silu", "gelu")
-
-# jax renamed TPUCompilerParams -> CompilerParams across 0.4.x/0.5.x; resolve
-# once here so every kernel module stays version-agnostic.
-CompilerParams = getattr(pltpu, "CompilerParams",
-                         getattr(pltpu, "TPUCompilerParams", None))
 
 
 def fused_epilogue(acc, bias=None, activation: Optional[str] = None):

@@ -1,10 +1,10 @@
 """Public jit'd wrappers around the Pallas kernels.
 
 Each wrapper: picks tile shapes (core.dataflow — the SPad/VMEM-fit constraint),
-pads inputs to tile multiples, dispatches the kernel, slices the result. On
-this CPU container kernels run with interpret=True (the Python interpreter of
-the kernel body); on TPU the same calls compile to Mosaic. ``INTERPRET`` is
-resolved once from the backend so call sites never care.
+pads inputs to tile multiples, dispatches the kernel, slices the result. On a
+TPU backend the kernels compile to Mosaic; on the CPU backend (the tests) they
+run with interpret=True, the Python interpreter of the kernel body. Any other
+backend is refused: :func:`interpret_mode` is the one place that decides.
 """
 from __future__ import annotations
 
@@ -25,8 +25,17 @@ from repro.kernels import paged_attention as _paged
 from repro.kernels import rs_matmul as _rs
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
+def interpret_mode() -> bool:
+    """False on a TPU backend (Mosaic), True on the CPU backend (Pallas
+    interpreter); any other backend raises rather than fall back."""
+    backend = jax.default_backend()
+    if backend == "tpu":
+        return False
+    if backend == "cpu":
+        return True
+    raise RuntimeError(
+        f"Pallas kernels compile for TPU or run interpreted on CPU; the "
+        f"{backend!r} backend has neither path")
 
 
 def _pad_to(x, m: int, axis: int):
@@ -47,7 +56,7 @@ def rs_matmul(x, w, *, bias=None, activation: Optional[str] = None,
     bias (N,) and ``activation`` fuse into the kernel's accumulator-flush
     epilogue (kernels/epilogue.py) — no second pass over the output.
     """
-    interpret = (not _on_tpu()) if interpret is None else interpret
+    interpret = interpret_mode() if interpret is None else interpret
     M, K = x.shape
     _, N = w.shape
     t = tiling or dataflow.rs_matmul_tiling(M, K, N, x.dtype.itemsize)
@@ -110,7 +119,7 @@ def bcsc_matmul(x, m: BCSCMatrix, *, bm: int = 0, bias=None,
     M ≤ GEMV_M_MAX takes the scratch-accumulator GEMV kernel, larger M the
     revisit-accumulate GEMM kernel. Pass ``bm`` to force a GEMM tile.
     """
-    interpret = (not _on_tpu()) if interpret is None else interpret
+    interpret = interpret_mode() if interpret is None else interpret
     blocks, row_ids, col_ids, n_out = prepare_bcsc(m)
     assert x.shape[1] == m.shape[0], (x.shape, m.shape)
     return _bcsc_apply(x, blocks, row_ids, col_ids, n_out=n_out, bm=bm,
@@ -147,7 +156,7 @@ def bcsc_apply_packed(x, packed, *, n_out: int, bias=None,
     params pytree leaf group (stacks under lax.scan, no host-side prep at
     trace time). n_out must be static (callers derive it from the config).
     """
-    interpret = (not _on_tpu()) if interpret is None else interpret
+    interpret = interpret_mode() if interpret is None else interpret
     return _bcsc_apply(x, packed["blocks"], packed["row_ids"],
                        packed["col_ids"], n_out=n_out, bm=0, bias=bias,
                        activation=activation, out_dtype=out_dtype,
@@ -181,7 +190,7 @@ def bcsc_mlp_packed(x, gate_packed, up_packed, down_packed, *, d_ff: int,
     (serve.sparse stores it as ``_bcsc_counts``); assembled here when absent.
     Callers should gate on ``core.dataflow.mlp_path(...) == 'fused'``.
     """
-    interpret = (not _on_tpu()) if interpret is None else interpret
+    interpret = interpret_mode() if interpret is None else interpret
     M = x.shape[0]
     bm = _plan.tile_m(M)
     xp = _pad_to(x, bm, 0)
@@ -221,7 +230,7 @@ def paged_attention(q, k_pool, v_pool, block_table, lengths, *,
     amax scales as ``k_scale``/``v_scale`` (P, KV) fp32; the kernel
     dequantizes each page inside its online-softmax loop.
     """
-    interpret = (not _on_tpu()) if interpret is None else interpret
+    interpret = interpret_mode() if interpret is None else interpret
     B, _, H, D = q.shape
     KV = k_pool.shape[2]
     R = H // KV
@@ -237,7 +246,7 @@ def sliding_window_attention(q, k, v, *, window: int, softcap: float = 0.0,
                              bq: int = 128, bkv: int = 128,
                              interpret: Optional[bool] = None):
     """q (B,S,H,D); k,v (B,S,KV,D) -> (B,S,H,D) fp32. Any S (padded)."""
-    interpret = (not _on_tpu()) if interpret is None else interpret
+    interpret = interpret_mode() if interpret is None else interpret
     B, S, H, D = q.shape
     bq = min(bq, max(8, S))
     bkv = min(bkv, max(8, S))

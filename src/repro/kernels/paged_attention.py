@@ -37,7 +37,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import dataflow
-from repro.kernels import epilogue as _epi
 
 NEG_INF = -2.0e38
 
@@ -94,8 +93,8 @@ def _paged_kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, *rest,
             # payload, so int8 pages never round-trip through a dense fp
             # buffer — the compressed-domain contract of the BCSC kernels
             # applied to KV-over-time
-            k = k * (ks_ref[0] * (1.0 / 127.0))[None, :, None]
-            v = v * (vs_ref[0] * (1.0 / 127.0))[None, :, None]
+            k = k * (ks_ref[...] * (1.0 / 127.0))        # (1, KV, 1)
+            v = v * (vs_ref[...] * (1.0 / 127.0))
         s = jnp.einsum("grd,tgd->grt", q, k,
                        preferred_element_type=jnp.float32)
         s = s * (1.0 / math.sqrt(q.shape[-1]))
@@ -155,7 +154,7 @@ def paged_attention_raw(q, k_pool, v_pool, block_table, lengths, *,
         return (jnp.clip(bt[b * MP + j], 0, P - 1), 0, 0, 0)
 
     def scale_map(b, j, bt, lens):
-        return (jnp.clip(bt[b * MP + j], 0, P - 1), 0)
+        return (jnp.clip(bt[b * MP + j], 0, P - 1), 0, 0)
 
     kernel = functools.partial(_paged_kernel, page_size=ps, max_pages=MP,
                                softcap=softcap, quantized=quantized)
@@ -166,9 +165,12 @@ def paged_attention_raw(q, k_pool, v_pool, block_table, lengths, *,
     ]
     operands = [q, k_pool, v_pool]
     if quantized:
-        in_specs += [pl.BlockSpec((1, KV), scale_map),
-                     pl.BlockSpec((1, KV), scale_map)]
-        operands += [k_scale, v_scale]
+        # scales ride as (P, KV, 1): a (1, KV, 1) block spans the array's
+        # last two dims, which the TPU tiling rule requires of a block
+        # narrower than (8, 128), and it broadcasts along D in the kernel
+        in_specs += [pl.BlockSpec((1, KV, 1), scale_map),
+                     pl.BlockSpec((1, KV, 1), scale_map)]
+        operands += [k_scale.reshape(P, KV, 1), v_scale.reshape(P, KV, 1)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(B, MP),
@@ -184,7 +186,7 @@ def paged_attention_raw(q, k_pool, v_pool, block_table, lengths, *,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, KV, R, D), out_dtype),
-        compiler_params=_epi.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(block_table.reshape(-1).astype(jnp.int32),

@@ -8,14 +8,7 @@ importing this module never touches jax device state — only launch/dryrun.py
 from __future__ import annotations
 
 import jax
-from jax.sharding import Mesh
-
-# AxisType landed after jax 0.4.x; older versions only have Auto meshes, which
-# is exactly what we request — so its absence changes nothing.
-try:
-    from jax.sharding import AxisType
-except ImportError:          # pragma: no cover - jax < 0.5
-    AxisType = None
+from jax.sharding import AxisType, Mesh
 
 
 def _make(shape, axes) -> Mesh:
@@ -26,9 +19,8 @@ def _make(shape, axes) -> Mesh:
     assert len(devs) >= n, (f"need {n} devices, have {len(devs)} — the dry-run "
                             "must set XLA_FLAGS=--xla_force_host_platform_"
                             "device_count=512 before importing jax")
-    kw = {} if AxisType is None else {
-        "axis_types": (AxisType.Auto,) * len(axes)}
-    return jax.make_mesh(shape, axes, devices=devs[:n], **kw)
+    return jax.make_mesh(shape, axes, devices=devs[:n],
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:

@@ -12,6 +12,7 @@ import jax
 
 from repro.configs import ARCH_NAMES, get_config
 from repro.core import plan as plan_lib
+from repro.launch import compile_cache
 from repro.models import transformer as tfm
 from repro.serve.engine import DecodeEngine, Request
 
@@ -28,6 +29,7 @@ def main():
     ap.add_argument("--temperature", type=float, default=0.0)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    compile_cache.enable()
 
     cfg = get_config(args.arch + ("-reduced" if args.reduced else ""))
     rng = jax.random.PRNGKey(args.seed)

@@ -20,7 +20,7 @@ import numpy as np
 from repro.configs import ARCH_NAMES, ShapeConfig, get_config
 from repro.core import planner
 from repro.data import pipeline as data_lib
-from repro.launch import mesh as mesh_lib
+from repro.launch import compile_cache, mesh as mesh_lib
 from repro.launch.cell import mesh_desc
 from repro.runtime.fault_tolerance import FaultToleranceConfig, Supervisor
 from repro.sharding import autoshard, specs as sh
@@ -43,6 +43,7 @@ def main():
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--log-every", type=int, default=10)
     args = ap.parse_args()
+    compile_cache.enable()
 
     cfg = get_config(args.arch + ("-reduced" if args.reduced else ""))
     mesh = mesh_lib.make_local_mesh()

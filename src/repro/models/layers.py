@@ -2,7 +2,9 @@
 
 Everything computes in bf16 with fp32 accumulation (``preferred_element_type``),
 normalizations and softmax in fp32 — the TPU analogue of the paper's
-8b MAC / 20b psum precision pair (DESIGN.md §7).
+8b MAC / 20b psum precision pair (DESIGN.md §7). Parameters are held in bf16,
+the dtype every matmul casts its weight to and the 2 B/param the serving plan
+counts; training keeps its own fp32 master copy (train.loop).
 """
 from __future__ import annotations
 
@@ -14,7 +16,7 @@ import jax
 import jax.numpy as jnp
 
 COMPUTE_DTYPE = jnp.bfloat16
-PARAM_DTYPE = jnp.float32
+PARAM_DTYPE = jnp.bfloat16
 ACCUM_DTYPE = jnp.float32
 
 
@@ -198,7 +200,6 @@ def _flash_call(q, k, v, cfg, mode: str, msize: int):
                and S % ms == 0 and (S // ms) >= 128)
     if use_seq:
         from jax.sharding import PartitionSpec as P
-        from repro.sharding.collectives import shard_map
         b_ax = h.act[0]
         S_loc = S // ms
 
@@ -209,7 +210,7 @@ def _flash_call(q, k, v, cfg, mode: str, msize: int):
                 q_loc, k_full, v_full, mode, msize,
                 cfg.attn_logit_softcap, min(blk, S_loc), blk, qpos=qpos)
 
-        out = shard_map(
+        out = jax.shard_map(
             body, mesh=h.mesh,
             in_specs=(P(b_ax, None, None, "model", None),
                       P(b_ax, None, None, None),

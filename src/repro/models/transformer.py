@@ -7,6 +7,7 @@ are applied unstacked after the scan.
 """
 from __future__ import annotations
 
+import functools
 import math
 from typing import Dict, Optional, Tuple
 
@@ -71,7 +72,10 @@ def _init_block(rng, cfg, kind: str, is_moe: bool):
     return p
 
 
+@functools.partial(jax.jit, static_argnums=1)
 def init_params(rng, cfg):
+    """Random parameters for ``cfg``, built inside one jitted program so
+    only the output pytree is allocated on the device."""
     period = scan_period(cfg)
     nper = num_scan_periods(cfg)
     rem = num_remainder(cfg)
@@ -115,9 +119,9 @@ def embed_tokens(params, tokens, cfg):
         x = jnp.zeros(tokens.shape[:1] + tokens.shape[2:] + (cfg.d_model,),
                       jnp.float32)
         for k in range(cfg.num_codebooks):
-            x = x + params["embed"][k].astype(jnp.float32)[tokens[:, k]]
+            x = x + params["embed"][k][tokens[:, k]].astype(jnp.float32)
     else:
-        x = params["embed"].astype(jnp.float32)[tokens]
+        x = params["embed"][tokens].astype(jnp.float32)
     if cfg.embed_scale:
         x = x * math.sqrt(cfg.d_model)
     return x.astype(COMPUTE_DTYPE)

@@ -69,7 +69,7 @@ class LLM:
         if plan is None:
             plan = plan_lib.plan_serve(
                 cfg,
-                hbm_budget_bytes=int(eyexam.HBM_CAP // 2),
+                hbm_budget_bytes=int(eyexam.device_peaks().hbm_bytes // 2),
                 expected_batch=DEFAULT_BATCH,
                 expected_len_dist=dict(DEFAULT_LEN_DIST))
         self.cfg = cfg
@@ -125,12 +125,14 @@ class LLM:
 
     def sharding_report(self) -> Dict:
         """Mesh + per-device pool stats for the most recent call: resolved
-        tp/ep, whether host devices back the mesh, single- vs per-device KV
-        pool bytes, and (after a sharded paged ``stream``) live per-shard
-        occupancy and the lockstep-divergence count."""
-        pool = getattr(self._scheduler, "pager", None) \
-            if self._scheduler is not None else None
-        return shard_lib.sharding_stats(self.cfg, self.plan, pool=pool)
+        tp/ep, the devices that hold the weights and the KV cache, single-
+        vs per-device KV pool bytes, and (after a sharded paged ``stream``)
+        live per-shard occupancy and the lockstep-divergence count."""
+        sched = self._scheduler
+        return shard_lib.sharding_stats(
+            self.cfg, self.plan, pool=getattr(sched, "pager", None),
+            params=self.params,
+            pool_devices=getattr(sched, "pool_devices", None))
 
     def _normalize(self, requests: Sequence[RequestLike], cls,
                    on_token: Optional[Callable] = None) -> List:

@@ -231,6 +231,7 @@ class ContinuousBatchingScheduler:
         self.host_syncs = 0
         self.phase_stats: Dict = {}
         self._live = None             # run-in-progress state (see _run_gen)
+        self.pool_devices: List[str] = []   # where the KV cache lives
         self._chunk = jax.jit(self._make_chunk_fn(), donate_argnums=(1,))
         self._refill = jax.jit(self._make_refill_fn(), donate_argnums=(1,))
         self._cow = jax.jit(self._make_cow_fn(), donate_argnums=(0,))
@@ -629,6 +630,7 @@ class ContinuousBatchingScheduler:
         admit_order: List[int] = []                  # rows, oldest first
         row_rids = [-1] * self.rows
         state = self._init_state()
+        self.pool_devices = shard_mod.devices_of(state[0])
         K = self.cfg.num_codebooks
         T = self.sync_every
         clock = 0.0

@@ -24,8 +24,8 @@ experts        INTERLEAVED_MC        the expert axis is a batch axis in the
                                      full-E tensor
 =============  ====================  =======================================
 
-This module owns the host side: :class:`ServeMesh` (the resolved mesh and
-whether real devices back it), :class:`ShardedPagePool` (per-device
+This module owns the host side: :class:`ServeMesh` (the resolved mesh),
+:class:`ShardedPagePool` (per-device
 ``PageAllocator``\\ s in lockstep over one distributed address space — the
 block table), partition specs that subsume what ``launch/cell``'s planner
 chose for the launch path, and the analytic collective accounting the
@@ -37,7 +37,7 @@ sharded execution bit-identical to single-device — lives in
 from __future__ import annotations
 
 import dataclasses
-from typing import Dict, Optional
+from typing import Dict, List, Optional, Sequence
 
 from repro.core import hmmesh
 from repro.serve import paging
@@ -46,11 +46,11 @@ from repro.serve import paging
 # -------------------------------------------------------------- serve mesh
 @dataclasses.dataclass(frozen=True)
 class ServeMesh:
-    """The resolved serving mesh: ``tp`` × ``ep`` devices, logical by
-    default. The sharded program is pure math (shard-explicit single-jit),
-    so it runs — and is tested bit-identical — on any host; ``backed``
-    reports whether enough real devices exist to place the shards
-    (the CI mesh8 job forces 8 host devices to exercise that path)."""
+    """The resolved serving mesh: ``tp`` × ``ep`` shards. The sharded
+    program is shard-explicit math inside one jit (``sharding.
+    tensor_parallel``), so today every shard runs on the one device that
+    holds the weights and the pool; :func:`sharding_stats` reports where
+    they actually live."""
     tp: int = 1
     ep: int = 1
 
@@ -67,32 +67,18 @@ class ServeMesh:
     def trivial(self) -> bool:
         return self.devices == 1
 
-    @property
-    def backed(self) -> bool:
-        import jax
-        return jax.device_count() >= self.devices
-
-    def device_mesh(self):
-        """A ``jax.sharding.Mesh`` over axes ``("ep", "tp")`` on the first
-        ``devices`` jax devices — only meaningful when :attr:`backed`."""
-        import jax
-        import numpy as np
-        from jax.sharding import Mesh
-        if not self.backed:
-            raise RuntimeError(
-                f"mesh tp={self.tp} ep={self.ep} needs {self.devices} "
-                f"device(s), host has {jax.device_count()} — run under "
-                "XLA_FLAGS=--xla_force_host_platform_device_count="
-                f"{self.devices} (the CI mesh8 job) or serve logically")
-        devs = np.array(jax.devices()[: self.devices]).reshape(
-            self.ep, self.tp)
-        return Mesh(devs, ("ep", "tp"))
-
     def describe(self) -> str:
-        import jax
-        backing = "backed" if self.backed else \
-            f"logical ({jax.device_count()} host device(s))"
-        return f"tp={self.tp} ep={self.ep} ({self.devices} devices, {backing})"
+        if self.trivial:
+            return "tp=1 ep=1 (1 device)"
+        return (f"tp={self.tp} ep={self.ep} ({self.devices} shards emulated "
+                "inside one program on one device)")
+
+
+def devices_of(tree) -> List[str]:
+    """The devices that hold the arrays of ``tree``, sorted."""
+    import jax
+    return sorted({str(d) for x in jax.tree.leaves(tree)
+                   for d in x.devices()})
 
 
 # --------------------------------------------------------- partition specs
@@ -101,8 +87,8 @@ def partition_specs(plan) -> Dict[str, Dict]:
     planner into the frozen plan: the same ``hmmesh.Mode`` vocabulary
     ``core.planner``/``sharding.autoshard`` used for the launch path, now
     read off the ServePlan's mesh decisions. Each entry names the mode and
-    the ``jax.sharding.PartitionSpec`` that realizes it on a
-    :meth:`ServeMesh.device_mesh` (KV pools are (P, page_size, KV, D):
+    the ``jax.sharding.PartitionSpec`` that would realize it on a
+    ``("ep", "tp")`` device mesh (KV pools are (P, page_size, KV, D):
     head axis 2 shards over tp; expert weights are (E, d, f): expert axis
     0 shards over ep; everything else replicates)."""
     from jax.sharding import PartitionSpec as P
@@ -242,15 +228,20 @@ def per_device_kv_bytes(cfg, plan) -> int:
     return total // (plan.tp if plan.tp > 1 else 1)
 
 
-def sharding_stats(cfg, plan, pool=None) -> Dict:
+def sharding_stats(cfg, plan, pool=None, params=None,
+                   pool_devices: Optional[Sequence[str]] = None) -> Dict:
     """One report block for examples/bench: the resolved mesh, per-device
-    pool bytes, and (when a pool is passed) live shard occupancy."""
+    pool bytes, (when a pool is passed) live shard occupancy, and where
+    the weights (``params``) and the KV pool (``pool_devices``, recorded
+    by the scheduler) actually live."""
     from repro.serve import kvcache
     mesh = ServeMesh.from_plan(plan)
     single = kvcache.kv_page_bytes(cfg, plan.page_size, plan.kv_quant) \
         * plan.num_pages if plan.paged else 0
     out = {"tp": mesh.tp, "ep": mesh.ep, "devices": mesh.devices,
-           "backed": mesh.backed,
+           "weights_devices": devices_of(params) if params is not None
+           else [],
+           "pool_devices": list(pool_devices or []),
            "kv_bytes_single_device": single,
            "kv_bytes_per_device": per_device_kv_bytes(cfg, plan)}
     if isinstance(pool, ShardedPagePool):

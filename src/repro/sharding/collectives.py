@@ -14,39 +14,13 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-try:  # location moved across jax versions
-    from jax import shard_map as _shard_map
-except Exception:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _shard_map  # type: ignore
-
-
-def axis_size(axis_name: str) -> int:
-    """Version-tolerant ``jax.lax.axis_size`` (absent before jax 0.6): the
-    psum-of-one idiom is statically folded to the mesh axis size."""
-    try:
-        return jax.lax.axis_size(axis_name)
-    except AttributeError:
-        return jax.lax.psum(1, axis_name)
-
-
-def shard_map(f, **kw):
-    """Version-tolerant shard_map (check_vma/check_rep kwarg renamed)."""
-    kw.pop("check_vma", None)
-    kw.pop("check_rep", None)
-    for flag in ({"check_vma": False}, {"check_rep": False}, {}):
-        try:
-            return _shard_map(f, **kw, **flag)
-        except TypeError:
-            continue
-    return _shard_map(f, **kw)
-
 
 def hierarchical_psum(x, pod_axis: str = "pod", inner_axis: str = "data"):
     """All-reduce over (pod × inner) as RS(inner) → AR(pod) → AG(inner).
 
     Equivalent to ``jax.lax.psum(x, (pod_axis, inner_axis))`` but inter-pod
     traffic carries only 1/inner of the payload. Call inside shard_map."""
-    n_inner = axis_size(inner_axis)
+    n_inner = jax.lax.axis_size(inner_axis)
     flat = x.reshape(-1)
     pad = (-flat.shape[0]) % n_inner
     if pad:
@@ -75,15 +49,15 @@ def allreduce_stacked(mesh: Mesh, x):
             return hierarchical_psum(v, "pod", "data")
         return jax.lax.psum(v, "data")
 
-    return shard_map(body, mesh=mesh, in_specs=P(axes), out_specs=P(),
-                     check_vma=False)(x)
+    return jax.shard_map(body, mesh=mesh, in_specs=P(axes), out_specs=P(),
+                         check_vma=False)(x)
 
 
 def ring_allgather(x, axis_name: str):
     """All-gather via (n-1) collective-permutes — an explicit ring schedule
     whose hops XLA can overlap with compute. Call inside shard_map; gathers
     along a new leading dim ordered by source index."""
-    n = axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name)
     perm = [(i, (i + 1) % n) for i in range(n)]
     chunks = [x]

@@ -47,9 +47,9 @@ def shard_slice(x, axis: int, shard: int, n: int):
 
 def all_gather(parts: List, axis: int):
     """The activation all-gather, lowered to canonical-device-order
-    concatenation — exact, which is the whole bit-identity argument. On a
-    backed mesh this is the one per-step wire collective (the plan's
-    ``noc_acts`` decision prices it)."""
+    concatenation — exact, which is the whole bit-identity argument. Once
+    shards live on their own devices this is the one per-step wire
+    collective (the plan's ``noc_acts`` decision prices it)."""
     return parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis=axis)
 
 

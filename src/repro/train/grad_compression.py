@@ -21,8 +21,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.sharding.collectives import shard_map
-
 from repro.train import optimizer as opt_lib
 
 
@@ -96,7 +94,7 @@ def make_compressed_dp_train_step(mesh: Mesh, loss_fn, opt_cfg):
 
     def step(params, opt_state, batch, ef):
         # prefix specs: replicated params/opt/metrics, dp-sharded batch/ef
-        f = shard_map(
+        f = jax.shard_map(
             body, mesh=mesh,
             in_specs=(P(), P(), P(dp), P(dp)),
             out_specs=(P(), P(), P(dp), P()),

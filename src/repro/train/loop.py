@@ -79,7 +79,10 @@ def make_eval_step(cfg) -> Callable:
 
 
 def init_train_state(rng, cfg) -> Tuple[dict, opt_lib.AdamWState]:
-    params = tfm.init_params(rng, cfg)
+    # fp32 master weights: a bf16 parameter would round away updates
+    # smaller than its last bit; the layers cast to bf16 at each use
+    params = jax.tree.map(lambda p: p.astype(jnp.float32),
+                          tfm.init_params(rng, cfg))
     return params, opt_lib.init_adamw(params)
 
 

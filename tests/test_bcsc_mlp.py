@@ -134,15 +134,6 @@ def test_fused_mlp_hidden_never_leaves_vmem():
                   "wd": sps.pack_weight(wd, 16, 16)}
     x = jnp.ones((1, 1, cfg.d_model), jnp.bfloat16)
     jaxpr = jax.make_jaxpr(lambda p, xx: layers.mlp(p, xx, cfg))(mlp_params, x)
-
-    def pallas_eqns(jpr):
-        for e in jpr.eqns:
-            if "pallas" in str(e.primitive):
-                yield e
-            for sub in jax.core.subjaxprs(e.params) \
-                    if hasattr(jax.core, "subjaxprs") else []:
-                yield from pallas_eqns(sub)
-
     calls = [e for e in jaxpr.jaxpr.eqns if "pallas" in str(e.primitive)]
     assert len(calls) == 1                       # megakernel: one fused call
     for v in calls[0].outvars:

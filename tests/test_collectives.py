@@ -78,18 +78,18 @@ def test_allreduce_stacked_one_device_is_identity_sum():
 def test_hierarchical_psum_one_device_identity():
     mesh = _mesh1("pod", "data")
     x = jnp.arange(10, dtype=jnp.float32).reshape(2, 5)
-    out = collectives.shard_map(
+    out = jax.shard_map(
         lambda v: collectives.hierarchical_psum(v, "pod", "data"),
-        mesh=mesh, in_specs=P(), out_specs=P())(x)
+        mesh=mesh, in_specs=P(), out_specs=P(), check_vma=False)(x)
     np.testing.assert_array_equal(np.asarray(out), np.asarray(x))
 
 
 def test_ring_allgather_one_device_identity():
     mesh = _mesh1("model")
     x = jnp.arange(6, dtype=jnp.float32).reshape(2, 3)
-    out = collectives.shard_map(
+    out = jax.shard_map(
         lambda v: collectives.ring_allgather(v, "model"),
-        mesh=mesh, in_specs=P(), out_specs=P("model"))(x)
+        mesh=mesh, in_specs=P(), out_specs=P("model"), check_vma=False)(x)
     assert out.shape == (1, 2, 3)     # new leading gather dim, 1 source
     np.testing.assert_array_equal(np.asarray(out[0]), np.asarray(x))
 
@@ -158,22 +158,21 @@ import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
 from repro.sharding import collectives
 
-try:
-    from jax.sharding import AxisType
-    kw = {"axis_types": (AxisType.Auto,) * 2}
-except ImportError:
-    kw = {}
-mesh = jax.make_mesh((2, 4), ("pod", "data"), **kw)
+from jax.sharding import AxisType
+mesh = jax.make_mesh((2, 4), ("pod", "data"),
+                     axis_types=(AxisType.Auto,) * 2)
 rng = np.random.default_rng(0)
 x = jnp.asarray(rng.standard_normal((8, 3, 5)), jnp.float32)
 
 # hierarchical RS->AR->AG == flat psum over both axes
-hier = collectives.shard_map(
+hier = jax.shard_map(
     lambda v: collectives.hierarchical_psum(v[0], "pod", "data"),
-    mesh=mesh, in_specs=P(("pod", "data")), out_specs=P())(x)
-flat = collectives.shard_map(
+    mesh=mesh, in_specs=P(("pod", "data")), out_specs=P(),
+    check_vma=False)(x)
+flat = jax.shard_map(
     lambda v: jax.lax.psum(v[0], ("pod", "data")),
-    mesh=mesh, in_specs=P(("pod", "data")), out_specs=P())(x)
+    mesh=mesh, in_specs=P(("pod", "data")), out_specs=P(),
+    check_vma=False)(x)
 np.testing.assert_allclose(np.asarray(hier), np.asarray(flat),
                            rtol=1e-6, atol=1e-6)
 
@@ -183,15 +182,16 @@ np.testing.assert_allclose(np.asarray(out), np.asarray(x.sum(0)),
                            rtol=1e-6, atol=1e-6)
 
 # ring all-gather == lax.all_gather (source-index order)
-mesh_m = jax.make_mesh((8,), ("model",), **({"axis_types": kw.get(
-    "axis_types", ())[:1]} if kw else {}))
+mesh_m = jax.make_mesh((8,), ("model",), axis_types=(AxisType.Auto,))
 y = jnp.asarray(rng.standard_normal((16, 4)), jnp.float32)
-ring = collectives.shard_map(
+ring = jax.shard_map(
     lambda v: collectives.ring_allgather(v, "model"),
-    mesh=mesh_m, in_specs=P("model"), out_specs=P("model"))(y)
-ref = collectives.shard_map(
+    mesh=mesh_m, in_specs=P("model"), out_specs=P("model"),
+    check_vma=False)(y)
+ref = jax.shard_map(
     lambda v: jax.lax.all_gather(v, "model"),
-    mesh=mesh_m, in_specs=P("model"), out_specs=P("model"))(y)
+    mesh=mesh_m, in_specs=P("model"), out_specs=P("model"),
+    check_vma=False)(y)
 np.testing.assert_array_equal(np.asarray(ring), np.asarray(ref))
 print("MULTI_OK")
 """

@@ -72,8 +72,6 @@ def test_hloparse_counts_loop_iterations():
     expect = 5 * 2 * 32 * 64 * 64          # 5 iterations x one (32,64)@(64,64)
     assert cost.flops == expect
     ca = compiled.cost_analysis()
-    if isinstance(ca, list):               # jax < 0.5 returns [dict]
-        ca = ca[0] if ca else {}
     assert ca.get("flops", 0) < expect     # the builtin undercounts
 
 

@@ -11,17 +11,11 @@ _SCRIPT = r"""
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp, numpy as np
-from jax.sharding import PartitionSpec as P
+from jax.sharding import AxisType, PartitionSpec as P
 from repro.models import flash
-from repro.sharding.collectives import shard_map
 
-try:
-    from jax.sharding import AxisType
-    _mesh_kw = {"axis_types": (AxisType.Auto,) * 2}
-except ImportError:  # jax < 0.5 — Auto is the only mesh kind
-    _mesh_kw = {}
-
-mesh = jax.make_mesh((2, 4), ("data", "model"), **_mesh_kw)
+mesh = jax.make_mesh((2, 4), ("data", "model"),
+                     axis_types=(AxisType.Auto,) * 2)
 B, KV, R, S, D = 2, 2, 2, 64, 16
 rng = np.random.default_rng(0)
 q = jnp.asarray(rng.standard_normal((B, KV, R, S, D)), jnp.float32)
@@ -36,7 +30,7 @@ def seq_sharded(qf, kf, vf, mode, msize):
         qpos = off + jnp.arange(S_loc, dtype=jnp.int32)
         return flash.flash_attention(q_loc, k_full, v_full, mode, msize,
                                      0.0, 16, 16, qpos=qpos)
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(P("data", None, None, "model", None),
                   P("data", None, None, None), P("data", None, None, None)),
