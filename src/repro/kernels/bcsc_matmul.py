@@ -114,6 +114,7 @@ def bcsc_matmul_raw(x, blocks, row_ids, col_ids, *, n_out: int, bm: int,
     return pl.pallas_call(
         _bcsc_kernel,
         grid_spec=grid_spec,
+        name="bcsc_matmul",
         out_shape=jax.ShapeDtypeStruct((M, n_out), out_dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
@@ -192,6 +193,7 @@ def bcsc_gemv_raw(x, blocks, row_ids, col_ids, *, n_out: int, bm: int,
         functools.partial(_bcsc_gemv_kernel, nnzb=nnzb,
                           activation=activation, has_bias=has_bias),
         grid_spec=grid_spec,
+        name="bcsc_gemv",
         out_shape=jax.ShapeDtypeStruct((bm, n_out), out_dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),

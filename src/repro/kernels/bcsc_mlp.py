@@ -317,6 +317,7 @@ def bcsc_mlp_raw(x, g_blocks, g_rows, g_cols, d_blocks, d_rows, d_cols,
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
+        name="bcsc_mlp",
         out_shape=jax.ShapeDtypeStruct((M, n_out), out_dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=semantics),

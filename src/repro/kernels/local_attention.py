@@ -104,6 +104,7 @@ def sliding_window_attention_raw(q, k, v, *, window: int, bq: int = 128,
     return pl.pallas_call(
         kernel,
         grid=(B, H, nq, nk_per_q),
+        name="local_attention",
         in_specs=[
             pl.BlockSpec((1, 1, bq, D), lambda b, h, iq, ik: (b, h, iq, 0)),
             pl.BlockSpec((1, 1, bkv, D), kv_index),

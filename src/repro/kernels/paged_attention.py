@@ -185,6 +185,7 @@ def paged_attention_raw(q, k_pool, v_pool, block_table, lengths, *,
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
+        name="paged_attention",
         out_shape=jax.ShapeDtypeStruct((B, KV, R, D), out_dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),

@@ -76,6 +76,7 @@ def rs_matmul_raw(x, w, *, bm: int, bk: int, bn: int, bias=None,
         functools.partial(_rs_matmul_kernel, nk=nk, activation=activation,
                           has_bias=has_bias),
         grid=(nm, nn, nk),
+        name="rs_matmul",
         in_specs=in_specs,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((M, N), out_dtype),

@@ -382,15 +382,19 @@ def _attn_decode(p, x, kind, cache_entry, pos, cfg, block_table=None):
         assert block_table is not None, "paged cache entry needs a block table"
         pos = jnp.asarray(pos)
         posv = jnp.broadcast_to(pos, (B,)).astype(jnp.int32)
-        new_entry = _paged_append(cache_entry, k[:, 0], v[:, 0], block_table,
-                                  posv)
+        # named scopes tag the step's parts in the compiled program's op
+        # metadata, where a device op of a profile is traced back to them
+        with jax.named_scope("kv_append"):
+            new_entry = _paged_append(cache_entry, k[:, 0], v[:, 0],
+                                      block_table, posv)
         scales = {}
         if is_quantized_entry(new_entry):
             scales = dict(k_scale=new_entry["pk_scale"],
                           v_scale=new_entry["pv_scale"])
-        ctx = _tpar.sharded_paged_attention(
-            q, new_entry["pk"], new_entry["pv"], block_table, posv + 1,
-            tp, softcap=cfg.attn_logit_softcap, **scales)
+        with jax.named_scope("paged_attention"):
+            ctx = _tpar.sharded_paged_attention(
+                q, new_entry["pk"], new_entry["pv"], block_table, posv + 1,
+                tp, softcap=cfg.attn_logit_softcap, **scales)
         return (layers.attn_out(p, ctx.astype(layers.COMPUTE_DTYPE)),
                 new_entry)
     cap = cache_entry["k"].shape[1]
@@ -436,14 +440,15 @@ def apply_block_decode(p, x, cond, kind, is_moe, cfg, cache_entry, pos,
         hc = rms_norm(x, p["pre_norm_cross"], cfg.norm_eps)
         x = x + layers.cross_attention(p["cross_attn"], hc, cond, cfg)
     if kind != "ssm":
-        h = rms_norm(x, p["pre_norm_mlp"], cfg.norm_eps)
-        if is_moe:
-            y, _ = moe_lib.moe_layer(p["moe"], h, cfg)
-        else:
-            y = layers.mlp(p["mlp"], h, cfg)
-        if cfg.use_post_norm:
-            y = rms_norm(y, p["post_norm_mlp"], cfg.norm_eps)
-        x = x + y
+        with jax.named_scope("mlp"):
+            h = rms_norm(x, p["pre_norm_mlp"], cfg.norm_eps)
+            if is_moe:
+                y, _ = moe_lib.moe_layer(p["moe"], h, cfg)
+            else:
+                y = layers.mlp(p["mlp"], h, cfg)
+            if cfg.use_post_norm:
+                y = rms_norm(y, p["post_norm_mlp"], cfg.norm_eps)
+            x = x + y
     return x, new_entry
 
 
@@ -484,8 +489,9 @@ def serve_step(params, cache, tokens, pos, cfg, cond=None, hints=None,
             x, new_cache["rem"][f"rem{j}"] = apply_block_decode(
                 params["rem"][f"rem{j}"], x, cond, *kinds[j], cfg,
                 cache["rem"][f"rem{j}"], pos, block_table)
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    logits = tfm.lm_logits(params, x, cfg)
+    with jax.named_scope("lm_head"):
+        x = rms_norm(x, params["final_norm"], cfg.norm_eps)
+        logits = tfm.lm_logits(params, x, cfg)
     return logits, new_cache
 
 

@@ -121,11 +121,12 @@ def make_decode_step(cfg, temperature: float, eos_id: int) -> Callable:
         # ``last`` is (B,V) for LMs, (B,K,V) for multi-codebook (musicgen) —
         # sample_temperature reduces the trailing axis either way; the first
         # codebook carries EOS.
-        nxt = sample_temperature(rng_i, last, temperature)
-        head = nxt[:, 0] if K > 1 else nxt
-        emit = live                          # emitted this step
-        budget = budget - emit.astype(jnp.int32)
-        live = live & (head != eos_id) & (budget > 0)
+        with jax.named_scope("sample"):
+            nxt = sample_temperature(rng_i, last, temperature)
+            head = nxt[:, 0] if K > 1 else nxt
+            emit = live                      # emitted this step
+            budget = budget - emit.astype(jnp.int32)
+            live = live & (head != eos_id) & (budget > 0)
         tok = nxt[..., None]                 # (B,1) or (B,K,1)
         logits, cache = decoding.serve_step(params, cache, tok, pos, cfg,
                                             block_table=block_table)

@@ -68,8 +68,11 @@ class StreamRequest:
     ``on_token`` — if set — is called as ``on_token(request, token)`` for
     every generated token, in order, at each sync boundary. ``out`` always
     accumulates regardless. Latency stamps (``admitted_at``,
-    ``first_token_at``, ``finished_at``) are on the same virtual clock;
-    ``finished_wall_s`` is wall-clock seconds from run start.
+    ``first_token_at``, ``finished_at``) are on the same virtual clock.
+    Their ``*_wall`` twins are host ``time.monotonic()`` seconds: when the
+    scheduler took the request (``inject`` or run start), first admitted
+    it, first saw one of its tokens on the host (before ``on_token``), and
+    resolved it. They are annotations, never part of the trace identity.
     """
     rid: int
     prompt: List[int]
@@ -81,7 +84,10 @@ class StreamRequest:
     admitted_at: Optional[float] = None
     first_token_at: Optional[float] = None
     finished_at: Optional[float] = None
-    finished_wall_s: Optional[float] = None
+    queued_wall: Optional[float] = None
+    admitted_wall: Optional[float] = None
+    first_token_wall: Optional[float] = None
+    finished_wall: Optional[float] = None
     preemptions: int = 0
     shared_tokens: int = 0       # prompt tokens served from adopted pages
                                  # at the most recent admission (CoW sharing)
@@ -523,40 +529,45 @@ class ContinuousBatchingScheduler:
         router dispatch land here). Same caller-bug validation as run start;
         a request whose ``arrival`` is already in the past is admissible at
         the next boundary."""
-        live = self._live
-        if live is None:
-            raise RuntimeError(
-                "inject() requires a run in progress (start_gen)")
-        for r in requests:
-            if r.rid in live["rids"]:
-                raise ValueError(
-                    f"request rid {r.rid} already known to this run — rids "
-                    "must be unique across the run, including re-routes")
-            total = len(r.prompt) + r.max_new
-            if r.max_new > 0 and total > self.cache_len:
-                raise ValueError(
-                    f"request {r.rid}: prompt ({len(r.prompt)}) + max_new "
-                    f"({r.max_new}) exceeds cache_len ({self.cache_len})")
-            if self.paged and r.max_new > 0 and dataflow.pages_for(
-                    total, self.page_size) > self.num_pages:
-                raise ValueError(
-                    f"request {r.rid} needs "
-                    f"{dataflow.pages_for(total, self.page_size)} pages, "
-                    f"pool has {self.num_pages}: it can never run")
-        for r in sorted(requests, key=lambda r: (r.arrival, r.rid)):
-            live["rids"].add(r.rid)
-            live["requests"].append(r)
-            if r.max_new <= 0:
-                r.done = True
-                r.finished_at = r.arrival
-                r.outcome = guard_mod.RequestOutcome(
-                    "ok", "empty generation budget", at_step=r.arrival)
-                if r.on_outcome is not None:
-                    r.on_outcome(r, r.outcome)
-                live["done"].append(r)
-            else:
-                live["pending"].append(r)
-        live["pending"].sort(key=lambda r: (r.arrival, r.rid))
+        with telemetry_mod.phase_timer(None, None, name="inject"):
+            live = self._live
+            if live is None:
+                raise RuntimeError(
+                    "inject() requires a run in progress (start_gen)")
+            now = time.monotonic()
+            for r in requests:
+                if r.rid in live["rids"]:
+                    raise ValueError(
+                        f"request rid {r.rid} already known to this run — "
+                        "rids must be unique across the run, including "
+                        "re-routes")
+                total = len(r.prompt) + r.max_new
+                if r.max_new > 0 and total > self.cache_len:
+                    raise ValueError(
+                        f"request {r.rid}: prompt ({len(r.prompt)}) + max_new "
+                        f"({r.max_new}) exceeds cache_len ({self.cache_len})")
+                if self.paged and r.max_new > 0 and dataflow.pages_for(
+                        total, self.page_size) > self.num_pages:
+                    raise ValueError(
+                        f"request {r.rid} needs "
+                        f"{dataflow.pages_for(total, self.page_size)} pages, "
+                        f"pool has {self.num_pages}: it can never run")
+            for r in sorted(requests, key=lambda r: (r.arrival, r.rid)):
+                if r.queued_wall is None:   # a re-routed one keeps its own
+                    r.queued_wall = now
+                live["rids"].add(r.rid)
+                live["requests"].append(r)
+                if r.max_new <= 0:
+                    r.done = True
+                    r.finished_at = r.arrival
+                    r.outcome = guard_mod.RequestOutcome(
+                        "ok", "empty generation budget", at_step=r.arrival)
+                    if r.on_outcome is not None:
+                        r.on_outcome(r, r.outcome)
+                    live["done"].append(r)
+                else:
+                    live["pending"].append(r)
+            live["pending"].sort(key=lambda r: (r.arrival, r.rid))
 
     def _run_gen(self, requests: List[StreamRequest], rng=None, chaos=None,
                  external: bool = False):
@@ -601,6 +612,10 @@ class ContinuousBatchingScheduler:
                     f"{dataflow.pages_for(total, self.page_size)} pages, "
                     f"pool has {self.num_pages}: it can never run")
         allreqs = list(requests)      # grows via inject() (failover re-routes)
+        now = time.monotonic()
+        for r in requests:
+            if r.queued_wall is None:
+                r.queued_wall = now
         pending = sorted(requests, key=lambda r: (r.arrival, r.rid))
         waiting: List[StreamRequest] = []
         done: List[StreamRequest] = []
@@ -687,7 +702,7 @@ class ContinuousBatchingScheduler:
             r.done = True
             if r.finished_at is None:
                 r.finished_at = clock
-            r.finished_wall_s = run_clock.elapsed_s()
+            r.finished_wall = time.monotonic()
             r.outcome = guard_mod.RequestOutcome(
                 status=status, reason=reason, at_step=clock,
                 degraded=tuple(r.degraded))
@@ -802,491 +817,537 @@ class ContinuousBatchingScheduler:
                 "decode_chunks": st["decode_chunks"]}
             if cmd == "stop":
                 break
-            if tick is not None and tick > clock:
-                st["idle_steps"] += tick - clock
-                clock = tick
-            if not (pending or waiting or active):
-                if not external:
-                    break             # self-paced: nothing can arrive later
-                continue              # lockstep: stay alive for inject()
+            with telemetry_mod.phase_timer(None, None, name="boundary"):
+                if tick is not None and tick > clock:
+                    st["idle_steps"] += tick - clock
+                    clock = tick
+                if not (pending or waiting or active):
+                    if not external:
+                        break         # self-paced: nothing can arrive later
+                    continue          # lockstep: stay alive for inject()
 
-            # ---- int8 degrade rung (boundary start, measured pressure) ----
-            # requantizing relieves pressure BEFORE this boundary's arrivals
-            # are judged for clamping/shedding, so rung 1 shadows rungs 2-3
-            if "int8_kv" in self._ladder and self.paged \
-                    and self.kv_quant == "fp" \
-                    and self.plan.num_pages_int8 > self.num_pages:
-                if self.pager.in_use / self.num_pages >= g.int8_pressure:
-                    state = self._degrade_to_int8(state, clock)
+                with telemetry_mod.phase_timer(None, None, name="admit"):
+                    # ---- int8 degrade rung (boundary start, measured
+                    # pressure): requantizing relieves pressure BEFORE this
+                    # boundary's arrivals are judged for clamping/shedding, so
+                    # rung 1 shadows rungs 2-3
+                    if "int8_kv" in self._ladder and self.paged \
+                            and self.kv_quant == "fp" \
+                            and self.plan.num_pages_int8 > self.num_pages:
+                        if self.pager.in_use / self.num_pages \
+                                >= g.int8_pressure:
+                            state = self._degrade_to_int8(state, clock)
 
-            # ---- arrivals (virtual clock; idle-jump when nothing to do) ----
-            while pending and pending[0].arrival <= clock + 1e-9:
-                r = pending.pop(0)
-                tr.event("queued", clock, cat="request", slot=slot,
-                         rid=r.rid)
-                m.count("requests_queued")
-                if g is not None and self.paged and self._ladder:
-                    # admission control at the front door: rungs 2-3 judge
-                    # each arrival against measured pool pressure
-                    pressure = self.pager.in_use / self.num_pages
-                    if "shed" in self._ladder and pressure >= g.shed_pressure:
-                        resolve(r, "shed",
-                                f"pool pressure {pressure:.2f} >= shed "
-                                f"threshold {g.shed_pressure:.2f} at arrival")
-                        continue
-                    if "clamp_max_new" in self._ladder \
-                            and pressure >= g.clamp_pressure \
-                            and r.max_new > g.clamp_max_new:
-                        r.max_new = g.clamp_max_new
-                        r.degraded.append("clamp_max_new")
-                        st["clamped_admissions"] += 1
-                        m.count("clamped_admissions")
-                        tr.event("degrade_rung", clock, cat="degrade",
-                                 slot=slot, rid=r.rid,
-                                 rung="clamp_max_new")
-                waiting.append(r)
+                    # ---- arrivals (virtual clock; idle-jump when idle) ----
+                    while pending and pending[0].arrival <= clock + 1e-9:
+                        r = pending.pop(0)
+                        tr.event("queued", clock, cat="request", slot=slot,
+                                 rid=r.rid)
+                        m.count("requests_queued")
+                        if g is not None and self.paged and self._ladder:
+                            # admission control at the front door: rungs 2-3
+                            # judge each arrival against measured pool pressure
+                            pressure = self.pager.in_use / self.num_pages
+                            if "shed" in self._ladder \
+                                    and pressure >= g.shed_pressure:
+                                resolve(r, "shed",
+                                        f"pool pressure {pressure:.2f} >= "
+                                        f"shed threshold {g.shed_pressure:.2f}"
+                                        " at arrival")
+                                continue
+                            if "clamp_max_new" in self._ladder \
+                                    and pressure >= g.clamp_pressure \
+                                    and r.max_new > g.clamp_max_new:
+                                r.max_new = g.clamp_max_new
+                                r.degraded.append("clamp_max_new")
+                                st["clamped_admissions"] += 1
+                                m.count("clamped_admissions")
+                                tr.event("degrade_rung", clock, cat="degrade",
+                                         slot=slot, rid=r.rid,
+                                         rung="clamp_max_new")
+                        waiting.append(r)
 
-            # ---- deadlines: expire whatever outlived arrival + ttl --------
-            if g is not None:
-                for r in list(waiting):
-                    dl = deadline_of(r)
-                    if dl is not None and clock + 1e-9 >= dl:
-                        waiting.remove(r)
-                        resolve(r, "expired",
-                                f"deadline (arrival {r.arrival:g} + ttl "
-                                f"{dl - r.arrival:g} steps) passed before "
-                                "admission")
-                for row, r in list(active.items()):
-                    dl = deadline_of(r)
-                    if dl is not None and clock + 1e-9 >= dl:
-                        evict_active(row, "expired",
-                                     f"deadline (arrival {r.arrival:g} + "
-                                     f"ttl {dl - r.arrival:g} steps) passed "
-                                     f"mid-generation; {len(r.out)} tokens "
-                                     "kept")
+                    # ---- deadlines: expire whatever outlived arrival + ttl --
+                    if g is not None:
+                        for r in list(waiting):
+                            dl = deadline_of(r)
+                            if dl is not None and clock + 1e-9 >= dl:
+                                waiting.remove(r)
+                                resolve(r, "expired",
+                                        f"deadline (arrival {r.arrival:g} + "
+                                        f"ttl {dl - r.arrival:g} steps) "
+                                        "passed before admission")
+                        for row, r in list(active.items()):
+                            dl = deadline_of(r)
+                            if dl is not None and clock + 1e-9 >= dl:
+                                evict_active(row, "expired",
+                                             f"deadline (arrival "
+                                             f"{r.arrival:g} + ttl "
+                                             f"{dl - r.arrival:g} steps) "
+                                             "passed mid-generation; "
+                                             f"{len(r.out)} tokens kept")
 
-            if not active and not waiting:
-                if external:
-                    continue      # lockstep: never idle-jump past the tick
-                if not pending:
-                    break
-                st["idle_steps"] += pending[0].arrival - clock
-                clock = pending[0].arrival
-                continue
-
-            # ---- page headroom for the active rows' next chunk ------------
-            # runs BEFORE admission: live rows reserve their chunk pages
-            # first, so a new request is never admitted (and batch-prefilled)
-            # only to be preempted at the same boundary — that would throw
-            # the prefill away and thrash under sustained pressure
-            stalled = False
-            span = self._chunk_span()     # T, or T*k under speculation
-            if self.paged:
-                for row in list(admit_order):         # oldest first
-                    if row not in active:
-                        continue
-                    r = active[row]
-                    need = min(row_pos[row] + span, self._final_len(r))
-                    while row in active and not ensure_pages(r.rid, need):
-                        if not preempt_latest():
-                            if g is None:
-                                raise RuntimeError(
-                                    "page pool exhausted with nothing left "
-                                    "to preempt — num_pages is too small")
-                            stalled = True
+                    if not active and not waiting:
+                        if external:
+                            continue  # lockstep: never idle-jump past the tick
+                        if not pending:
                             break
-                    if stalled:
-                        break
-                    if row in active:
-                        self.pager.set_length(r.rid, row_pos[row])
-            clear_preempted_flags()
-            if stalled:
-                note_stall("no page headroom for the next chunk")
-                clock += T
-                continue
-
-            # ---- admission: arrived requests into freed rows --------------
-            to_admit: List[StreamRequest] = []
-            while waiting and len(to_admit) < alloc.available():
-                r = waiting[0]
-                if r.rid in just_preempted:
-                    # evicted THIS boundary to relieve pressure — re-admitting
-                    # into the pages it just freed would re-run its (growing)
-                    # prefill only to preempt it again: wait one boundary.
-                    # break, not skip: it keeps queue priority
-                    break
-                plen = self._plen(r)
-                if self.paged:
-                    # CoW prefix sharing: point leading table entries at
-                    # resident pages already holding this prompt's prefix
-                    # (refcount++); prefill will skip writes before the
-                    # boundary. Roll the adoption back if the fresh-page
-                    # remainder doesn't fit — all-or-nothing, like ensure.
-                    r.shared_tokens = self.pager.adopt_prefix(
-                        r.rid, self._resume_prompt(r)) \
-                        if self.share_prefix else 0
-                    if not ensure_pages(
-                            r.rid, min(plen + span, self._final_len(r))):
-                        if self.pager.pages_of(r.rid):
-                            self.pager.free(r.rid)   # roll back adoption
-                        r.shared_tokens = 0
-                        break                  # page pressure: wait for frees
-                    if self.share_prefix:
-                        # publish this prompt's pages immediately — their
-                        # content lands in this same boundary's refill, so a
-                        # same-boundary arrival can already adopt the chain
-                        self.pager.register_prefix(r.rid,
-                                                   self._resume_prompt(r))
-                waiting.pop(0)
-                to_admit.append(r)
-            just_preempted.clear()
-            admits: List[Tuple[int, StreamRequest]] = list(
-                zip(alloc.alloc_many(len(to_admit)), to_admit))
-            for row, r in admits:
-                admit_order.append(row)
-                row_rids[row] = r.rid
-                row_pos[row] = self._plen(r)
-                if self.paged:
-                    self.pager.set_length(r.rid, row_pos[row])
-                    st["shared_tokens_admitted"] += r.shared_tokens
-                if r.admitted_at is None:
-                    r.admitted_at = clock
-                    m.count("requests_admitted")
-                    wait = clock - r.arrival
-                    m.observe("admission_wait_steps", wait)
-                    m.tenant_observe(r.tenant, "admission_wait_steps", wait)
-                    tr.event("admitted", clock, cat="request", slot=slot,
-                             rid=r.rid, shared_tokens=r.shared_tokens)
-                if self.paged and r.shared_tokens:
-                    m.count("shared_tokens_admitted", r.shared_tokens)
-            if admits:
-                # recompute-resume fast path (ISSUE 9 satellite): a preempted
-                # request re-admitted while its leading pages are still
-                # resident (adopt_prefix above re-pointed the table at them)
-                # refills only the non-adopted suffix through the flattened
-                # verifier — one dispatch over len(suffix) rows instead of a
-                # full-prompt prefill tier. Partial coverage is page-aligned
-                # by construction (a partial-tail index key matches only the
-                # entire remainder), so the suffix starts on a fresh
-                # (unshared) page and its writes need no CoW.
-                fast: List[Tuple[int, StreamRequest]] = []
-                if self._fast_resume:
-                    fast = [(row, r) for row, r in admits
-                            if r.out and 0 < r.shared_tokens < self._plen(r)
-                            and r.shared_tokens % self.page_size == 0]
-                    fast_rows = {row for row, _ in fast}
-                    admits = [a for a in admits if a[0] not in fast_rows]
-                buckets: Dict[int, List[Tuple[int, StreamRequest]]] = {}
-                for row, r in admits:
-                    buckets.setdefault(self.plan.tier(self._plen(r)),
-                                       []).append((row, r))
-                bt = self._block_table(row_rids) if self.paged else \
-                    jnp.zeros((self.rows, 1), jnp.int32)
-                with telemetry_mod.phase_timer(
-                        st, "prefill_s", tracer=tr, name="prefill",
-                        start=clock, slot=slot) as ph:
-                    for row, r in fast:
-                        active[row] = r
-                        prompt = self._resume_prompt(r)
-                        cov = r.shared_tokens
-                        suffix = prompt[cov:]
-                        Lp = 1 << (len(suffix) - 1).bit_length()
-                        hrow = np.full((self.cache_len,), -1, np.int32)
-                        hrow[:len(prompt)] = prompt
-                        state = self._resume(
-                            self.params, state,
-                            jnp.asarray([suffix + [0] * (Lp - len(suffix))],
-                                        jnp.int32),
-                            jnp.asarray(cov, jnp.int32),
-                            jnp.asarray(len(suffix), jnp.int32),
-                            jnp.asarray(row, jnp.int32),
-                            jnp.asarray(len(prompt), jnp.int32),
-                            jnp.asarray(r.max_new - len(r.out), jnp.int32),
-                            bt, jnp.asarray(hrow))
-                        st["resume_fast_prompts"] += 1
-                        st["resume_fast_tokens"] += cov
-                        st["prefill_real_tokens"] += len(suffix)
-                        tr.event("resume_fast", clock, cat="request",
-                                 slot=slot, rid=r.rid, adopted=cov,
-                                 suffix=len(suffix))
-                    for tier, group in sorted(buckets.items()):
-                        B = len(group)
-                        toks, lengths, row_ids, budgets, starts = \
-                            build_tier_batch(
-                                group, tier, self._resume_prompt,
-                                lambda r: r.max_new - len(r.out),
-                                lambda r: r.shared_tokens)
-                        for row, r in group:
-                            active[row] = r
-                        state = self._refill(self.params, state,
-                                             jnp.asarray(toks),
-                                             jnp.asarray(lengths),
-                                             jnp.asarray(row_ids),
-                                             jnp.asarray(budgets), bt,
-                                             jnp.asarray(starts))
-                        real = int(lengths.sum())
-                        st["prefill_batches"] += 1
-                        st["prefill_prompts"] += B
-                        st["prefill_real_tokens"] += real
-                        st["prefill_padded_tokens"] += B * tier
-                        m.count("prefill_batches")
-                        m.count("prefill_prompts", B)
-                        m.count("prefill_real_tokens", real)
-                        m.count("prefill_padded_tokens", B * tier)
-                    ph.ready(state[1])
-                    ph.note(prompts=len(admits) + len(fast),
-                            tiers=len(buckets))
-
-            if not active:
-                if g is not None or inj is not None:
-                    # nothing running and nothing admitted (transient chaos
-                    # ensure-failures can starve admission): advance the
-                    # clock so arrivals/deadlines keep progressing
-                    st["idle_steps"] += T
-                    clock += T
-                continue
-            st["peak_live_rows"] = max(st["peak_live_rows"], len(active))
-
-            # ---- CoW guard: materialize shared pages this chunk appends to
-            # (runs after admission so freshly adopted whole-prompt tails are
-            # covered too; shared pages are read-only by contract)
-            if self.paged and self.share_prefix:
-                pairs: List[Tuple[int, int]] = []
-                for row in list(admit_order):         # oldest first
-                    if row not in active:
+                        st["idle_steps"] += pending[0].arrival - clock
+                        clock = pending[0].arrival
                         continue
-                    r = active[row]
-                    lo = row_pos[row]
-                    hi = min(lo + span, self._final_len(r))
-                    # re-probe after every mutation: a preemption can drop a
-                    # refcount to 1 mid-loop (page no longer needs a copy)
-                    while row in active:
-                        shared = self.pager.shared_pages_in(r.rid, lo, hi)
-                        if not shared:
-                            break
-                        pair = self.pager.cow_page(r.rid, shared[0])
-                        if pair is None:              # no free page: pressure
-                            if not preempt_latest():
-                                if g is None:
-                                    raise RuntimeError(
-                                        "page pool exhausted during CoW "
-                                        "materialization with nothing left "
-                                        "to preempt — num_pages is too "
-                                        "small")
-                                stalled = True
-                                break
-                            continue
-                        pairs.append(pair)
-                    if stalled:
-                        break
-                if pairs:
-                    # apply collected copies even on a stalled boundary: the
-                    # allocator already repointed those tables, so the device
-                    # content copy must land before anything reads the pages
-                    st["cow_copies"] += len(pairs)
-                    m.count("cow_copies", len(pairs))
-                    tr.event("cow_copy", clock, cat="pool", slot=slot,
-                             pages=len(pairs))
-                    # pad to a power of two (bounded retraces); pads repeat a
-                    # real pair so duplicate dsts carry identical content
-                    n = 1 << (len(pairs) - 1).bit_length()
-                    pairs = pairs + [pairs[0]] * (n - len(pairs))
-                    src = jnp.asarray([s for s, _ in pairs], jnp.int32)
-                    dst = jnp.asarray([d for _, d in pairs], jnp.int32)
-                    state = self._cow(state, src, dst)
-            clear_preempted_flags()       # CoW-guard preemptions, pre-chunk
-            if stalled:
-                note_stall("no free page for CoW materialization")
-                clock += T
-                continue
 
-            if self.paged:
-                # sample occupancy at the busiest point of the boundary —
-                # the end-of-run snapshot is always fully drained
-                s = self.pager.stats()
-                if peak_pages is None or \
-                        s["pages_used"] > peak_pages["pages_used"]:
-                    peak_pages = s
-
-            # ---- transient step faults (chaos): retry with backoff --------
-            # injected BEFORE the device dispatch (the chunk's state arg is
-            # donated — a post-dispatch replay would reuse consumed buffers)
-            # and BEFORE the rng split, so retried boundaries consume no
-            # randomness and survivors stay bit-identical to a clean run
-            if inj is not None:
-                attempt, aborted = 0, False
-                while True:
-                    try:
-                        inj.check_step(st["decode_chunks"])
-                        break
-                    except chaos_mod.InjectedFault as e:
-                        attempt += 1
-                        st["step_retries"] += 1
-                        m.count("step_retries")
-                        limit = g.max_step_retries if g is not None else 3
-                        if attempt > limit:
-                            reason = (f"decode step failing persistently "
-                                      f"({e}) — {limit} retries spent")
-                            for row in list(active):
-                                evict_active(row, "failed", reason)
-                            for r in list(waiting) + list(pending):
-                                resolve(r, "failed", reason)
-                            waiting.clear()
-                            pending.clear()
-                            aborted = True
-                            break
-                        time.sleep(backoff_delay(
-                            attempt, g.backoff_s if g is not None else 0.0))
-                if aborted:
-                    clear_preempted_flags()
-                    continue
-
-            # ---- NaN quarantine (pre-chunk): state[1] holds the logits
-            # the previous chunk (or prefill) produced for each row — a
-            # non-finite value there means this row's next sampled token
-            # would be garbage. Sweep at the boundary, evict poisoned rows
-            # BEFORE dispatching the chunk, so they emit nothing. (Chaos
-            # poisons the same buffer, so injection and genuine NaNs take
-            # the identical detection path. In-scan NaNs are caught one
-            # boundary late — tokens of the chunk that produced them may
-            # include garbage; the terminal outcome says so.)
-            if inj is not None:
-                prids = set(inj.nan_rids_for(st["decode_chunks"]))
-                prows = [row for row, r in active.items() if r.rid in prids]
-                if prows:
-                    cache_c, last_c = state[0], state[1]
-                    last_c = last_c.at[jnp.asarray(prows)].set(jnp.nan)
-                    state = (cache_c, last_c) + state[2:]
-            if g is not None and (g.nan_check or inj is not None):
-                bad = jax.device_get(jnp.isnan(
-                    state[1]).reshape(self.rows, -1).any(axis=1))
-                for row in [int(i) for i in np.nonzero(bad)[0]
-                            if int(i) in active]:
-                    r = active[row]
-                    evict_active(row, "failed",
-                                 "non-finite logits at the sync boundary; "
-                                 f"{len(r.out)} tokens kept")
-                clear_preempted_flags()
-                if not active:
-                    st["idle_steps"] += T
-                    clock += T
-                    continue
-
-            # ---------------------- device-resident decode chunk ----------
-            # under speculation the chunk runs against CoW forks of each
-            # row's page chain (refcount++, zero copies): draft writes land
-            # in the fork's tail headroom, commit adopts the fork table
-            # after the device round-trip, and any abort between simply
-            # drops the refcounts — no rollback scatter (ISSUE 9)
-            # fork child ids live at -2 - rid: real rids are >= 0 and -1 is
-            # the empty-device-row sentinel in row_rids, so ~0 == -1 would
-            # hand a dead row the fork's page table and let its flattened
-            # verify writes clobber the parent's KV
-            fork_rids: List[int] = []
-            if self.spec_on:
-                for row in list(admit_order):
-                    if row in active:
-                        rid = active[row].rid
-                        self.pager.fork_chain(rid, -2 - rid)
-                        fork_rids.append(rid)
-            with telemetry_mod.phase_timer(
-                    st, "decode_s", tracer=tr, name="decode_chunk",
-                    start=clock, end=clock + T, slot=slot) as ph:
-                rng, k = jax.random.split(rng)
-                bt = self._block_table(row_rids) if self.paged else \
-                    jnp.zeros((self.rows, 1), jnp.int32)
-                state, toks, emits = self._chunk(self.params, state, k, bt)
-                toks_h, emits_h, live_h = jax.device_get(
-                    (toks, emits, state[3]))
-                ph.note(rows=len(active))
-            for rid in fork_rids:
-                self.pager.commit_fork(rid, -2 - rid)
-            self.host_syncs += 1
-            st["decode_chunks"] += 1
-            st["decode_steps"] += T
-            m.count("decode_chunks")
-            m.count("decode_steps", T)
-            stall_streak = 0
-            clock += T
-            # window-end gauges, sampled while this chunk's rows are still
-            # resident (pre-eviction) — the per-window occupancy record the
-            # plan-drift detector measures against
-            m.gauge("queue_pending", len(pending))
-            m.gauge("queue_waiting", len(waiting))
-            m.gauge("active_rows", len(active))
-            if self.paged:
-                self.pager.observe(m)
-            m.end_window(clock, slot)
-            emitted = 0
-            spec = emits_h.ndim == 3          # (T, B, k) speculative chunk
-            if spec:
-                for t in range(emits_h.shape[0]):
-                    for row, r in active.items():
-                        for i in range(emits_h.shape[2]):
-                            if emits_h[t, row, i]:
-                                tok = int(toks_h[t, row, i])
-                                r.out.append(tok)
-                                emitted += 1
-                                if r.first_token_at is None:
-                                    r.first_token_at = clock - T + t + 1
-                                if r.on_token is not None:
-                                    r.on_token(r, tok)
-                drafted = emits_h.shape[0] * emits_h.shape[2] * len(active)
-                st["spec_rounds"] += emits_h.shape[0]
-                st["spec_drafted_tokens"] += drafted
-                st["spec_accepted_tokens"] += emitted
-                m.count("spec_rounds", emits_h.shape[0])
-                m.count("spec_drafted_tokens", drafted)
-                m.count("spec_accepted_tokens", emitted)
-                tr.event("spec_chunk", clock, cat="spec", slot=slot,
-                         drafted=drafted, accepted=emitted)
-            else:
-                for t in range(emits_h.shape[0]):
-                    for row, r in active.items():
-                        if emits_h[t, row]:
-                            tok = [int(v) for v in toks_h[t, row]] if K > 1 \
-                                else int(toks_h[t, row])
-                            r.out.append(tok)
-                            emitted += 1
-                            if r.first_token_at is None:
-                                r.first_token_at = clock - T + t + 1
-                            if r.on_token is not None:
-                                r.on_token(r, tok)
-            m.count("tokens_emitted", emitted)
-            if getattr(self.plan, "sharded", False):
-                # analytic collective traffic for this chunk (ISSUE 10):
-                # counted under the frozen collective_* keys so drift
-                # detection can compare measured all-gather bytes per token
-                # against the mesh decision's model
-                cc = shard_mod.chunk_collectives(self.plan, steps=T,
-                                                 tokens=emitted)
-                for key, val in cc.items():
-                    m.count(key, val)
-                if cc:
-                    tr.event("collective_chunk", clock, cat="collective",
-                             slot=slot, **cc)
-            freed_rows: List[int] = []
-            for row in list(active):
-                # mirror the device pos: baseline rows advance one per scan
-                # step; speculative rows advance by their accepted count
-                row_pos[row] += int(emits_h[:, row, :].sum()) if spec else T
-                if not live_h[row]:
-                    r = active.pop(row)
-                    freed_rows.append(row)
-                    admit_order.remove(row)
-                    row_rids[row] = -1
-                    row_pos.pop(row, None)
+                    # ---- page headroom for the active rows' next chunk ------
+                    # runs BEFORE admission: live rows reserve their chunk
+                    # pages first, so a new request is never admitted (and
+                    # batch-prefilled) only to be preempted at the same
+                    # boundary — that would throw the prefill away and thrash
+                    # under sustained pressure
+                    stalled = False
+                    span = self._chunk_span()     # T, or T*k under speculation
                     if self.paged:
-                        self.pager.free(r.rid)   # pages return immediately
-                    resolve(r, "ok")
-            alloc.free_many(freed_rows)
+                        for row in list(admit_order):         # oldest first
+                            if row not in active:
+                                continue
+                            r = active[row]
+                            need = min(row_pos[row] + span,
+                                       self._final_len(r))
+                            while row in active \
+                                    and not ensure_pages(r.rid, need):
+                                if not preempt_latest():
+                                    if g is None:
+                                        raise RuntimeError(
+                                            "page pool exhausted with nothing "
+                                            "left to preempt — num_pages is "
+                                            "too small")
+                                    stalled = True
+                                    break
+                            if stalled:
+                                break
+                            if row in active:
+                                self.pager.set_length(r.rid, row_pos[row])
+                    clear_preempted_flags()
+                    if stalled:
+                        note_stall("no page headroom for the next chunk")
+                        clock += T
+                        continue
 
-            if g is not None and g.audit_every_sync and self.paged:
-                # debug/CI mode: the full pool invariant audit after every
-                # sync window — leaks surface at the boundary that caused
-                # them, not as an end-of-run mystery
-                guard_mod.assert_pool_clean(self.pager, tracer=tr,
-                                            clock=clock, slot=slot)
+                    # ---- admission: arrived requests into freed rows --------
+                    to_admit: List[StreamRequest] = []
+                    while waiting and len(to_admit) < alloc.available():
+                        r = waiting[0]
+                        if r.rid in just_preempted:
+                            # evicted THIS boundary to relieve pressure —
+                            # re-admitting into the pages it just freed would
+                            # re-run its (growing) prefill only to preempt it
+                            # again: wait one boundary. break, not skip: it
+                            # keeps queue priority
+                            break
+                        plen = self._plen(r)
+                        if self.paged:
+                            # CoW prefix sharing: point leading table entries
+                            # at resident pages already holding this prompt's
+                            # prefix (refcount++); prefill will skip writes
+                            # before the boundary. Roll the adoption back if
+                            # the fresh-page remainder doesn't fit —
+                            # all-or-nothing, like ensure.
+                            r.shared_tokens = self.pager.adopt_prefix(
+                                r.rid, self._resume_prompt(r)) \
+                                if self.share_prefix else 0
+                            if not ensure_pages(
+                                    r.rid,
+                                    min(plen + span, self._final_len(r))):
+                                if self.pager.pages_of(r.rid):
+                                    self.pager.free(r.rid)  # roll back
+                                r.shared_tokens = 0
+                                break          # page pressure: wait for frees
+                            if self.share_prefix:
+                                # publish this prompt's pages immediately —
+                                # their content lands in this same boundary's
+                                # refill, so a same-boundary arrival can
+                                # already adopt the chain
+                                self.pager.register_prefix(
+                                    r.rid, self._resume_prompt(r))
+                        waiting.pop(0)
+                        to_admit.append(r)
+                    just_preempted.clear()
+                    admits: List[Tuple[int, StreamRequest]] = list(
+                        zip(alloc.alloc_many(len(to_admit)), to_admit))
+                    for row, r in admits:
+                        admit_order.append(row)
+                        row_rids[row] = r.rid
+                        row_pos[row] = self._plen(r)
+                        if self.paged:
+                            self.pager.set_length(r.rid, row_pos[row])
+                            st["shared_tokens_admitted"] += r.shared_tokens
+                        if r.admitted_at is None:
+                            r.admitted_at = clock
+                            r.admitted_wall = time.monotonic()
+                            m.count("requests_admitted")
+                            wait = clock - r.arrival
+                            m.observe("admission_wait_steps", wait)
+                            m.tenant_observe(r.tenant, "admission_wait_steps",
+                                             wait)
+                            tr.event("admitted", clock, cat="request",
+                                     slot=slot, rid=r.rid,
+                                     shared_tokens=r.shared_tokens)
+                        if self.paged and r.shared_tokens:
+                            m.count("shared_tokens_admitted", r.shared_tokens)
+                    # recompute-resume fast path: a preempted request
+                    # re-admitted while its leading pages are still resident
+                    # (adopt_prefix above re-pointed the table at them)
+                    # refills only the non-adopted suffix through the
+                    # flattened verifier — one dispatch over len(suffix) rows
+                    # instead of a full-prompt prefill tier.
+                    # Partial coverage is page-aligned by construction (a
+                    # partial-tail index key matches only the entire
+                    # remainder), so the suffix starts on a fresh (unshared)
+                    # page and its writes need no CoW.
+                    fast: List[Tuple[int, StreamRequest]] = []
+                    if self._fast_resume:
+                        fast = [(row, r) for row, r in admits if r.out
+                                and 0 < r.shared_tokens < self._plen(r)
+                                and r.shared_tokens % self.page_size == 0]
+                        fast_rows = {row for row, _ in fast}
+                        admits = [a for a in admits if a[0] not in fast_rows]
+                    buckets: Dict[int, List[Tuple[int, StreamRequest]]] = {}
+                    for row, r in admits:
+                        buckets.setdefault(self.plan.tier(self._plen(r)),
+                                           []).append((row, r))
+                    if fast or buckets:
+                        bt = self._block_table(row_rids) if self.paged else \
+                            jnp.zeros((self.rows, 1), jnp.int32)
+                if fast or buckets:
+                    with telemetry_mod.phase_timer(
+                            st, "prefill_s", tracer=tr, name="prefill",
+                            start=clock, slot=slot) as ph:
+                        for row, r in fast:
+                            active[row] = r
+                            prompt = self._resume_prompt(r)
+                            cov = r.shared_tokens
+                            suffix = prompt[cov:]
+                            Lp = 1 << (len(suffix) - 1).bit_length()
+                            hrow = np.full((self.cache_len,), -1, np.int32)
+                            hrow[:len(prompt)] = prompt
+                            state = self._resume(
+                                self.params, state,
+                                jnp.asarray(
+                                    [suffix + [0] * (Lp - len(suffix))],
+                                    jnp.int32),
+                                jnp.asarray(cov, jnp.int32),
+                                jnp.asarray(len(suffix), jnp.int32),
+                                jnp.asarray(row, jnp.int32),
+                                jnp.asarray(len(prompt), jnp.int32),
+                                jnp.asarray(r.max_new - len(r.out), jnp.int32),
+                                bt, jnp.asarray(hrow))
+                            st["resume_fast_prompts"] += 1
+                            st["resume_fast_tokens"] += cov
+                            st["prefill_real_tokens"] += len(suffix)
+                            tr.event("resume_fast", clock, cat="request",
+                                     slot=slot, rid=r.rid, adopted=cov,
+                                     suffix=len(suffix))
+                        for tier, group in sorted(buckets.items()):
+                            B = len(group)
+                            toks, lengths, row_ids, budgets, starts = \
+                                build_tier_batch(
+                                    group, tier, self._resume_prompt,
+                                    lambda r: r.max_new - len(r.out),
+                                    lambda r: r.shared_tokens)
+                            for row, r in group:
+                                active[row] = r
+                            state = self._refill(self.params, state,
+                                                 jnp.asarray(toks),
+                                                 jnp.asarray(lengths),
+                                                 jnp.asarray(row_ids),
+                                                 jnp.asarray(budgets), bt,
+                                                 jnp.asarray(starts))
+                            real = int(lengths.sum())
+                            st["prefill_batches"] += 1
+                            st["prefill_prompts"] += B
+                            st["prefill_real_tokens"] += real
+                            st["prefill_padded_tokens"] += B * tier
+                            m.count("prefill_batches")
+                            m.count("prefill_prompts", B)
+                            m.count("prefill_real_tokens", real)
+                            m.count("prefill_padded_tokens", B * tier)
+                        ph.note(prompts=len(admits) + len(fast),
+                                tiers=len(buckets))
+
+                if not active:
+                    if g is not None or inj is not None:
+                        # nothing running and nothing admitted (transient chaos
+                        # ensure-failures can starve admission): advance the
+                        # clock so arrivals/deadlines keep progressing
+                        st["idle_steps"] += T
+                        clock += T
+                    continue
+                st["peak_live_rows"] = max(st["peak_live_rows"], len(active))
+
+                # ---- CoW guard: materialize shared pages this chunk appends
+                # to (runs after admission so freshly adopted whole-prompt
+                # tails are covered too; shared pages are read-only by
+                # contract)
+                with telemetry_mod.phase_timer(None, None, name="cow"):
+                    if self.paged and self.share_prefix:
+                        pairs: List[Tuple[int, int]] = []
+                        for row in list(admit_order):         # oldest first
+                            if row not in active:
+                                continue
+                            r = active[row]
+                            lo = row_pos[row]
+                            hi = min(lo + span, self._final_len(r))
+                            # re-probe after every mutation: a preemption can
+                            # drop a refcount to 1 mid-loop (page no longer
+                            # needs a copy)
+                            while row in active:
+                                shared = self.pager.shared_pages_in(
+                                    r.rid, lo, hi)
+                                if not shared:
+                                    break
+                                pair = self.pager.cow_page(r.rid, shared[0])
+                                if pair is None:    # no free page: pressure
+                                    if not preempt_latest():
+                                        if g is None:
+                                            raise RuntimeError(
+                                                "page pool exhausted during "
+                                                "CoW materialization with "
+                                                "nothing left to preempt — "
+                                                "num_pages is too small")
+                                        stalled = True
+                                        break
+                                    continue
+                                pairs.append(pair)
+                            if stalled:
+                                break
+                        if pairs:
+                            # apply collected copies even on a stalled
+                            # boundary: the allocator already repointed those
+                            # tables, so the device content copy must land
+                            # before anything reads the pages
+                            st["cow_copies"] += len(pairs)
+                            m.count("cow_copies", len(pairs))
+                            tr.event("cow_copy", clock, cat="pool", slot=slot,
+                                     pages=len(pairs))
+                            # pad to a power of two (bounded retraces); pads
+                            # repeat a real pair so duplicate dsts carry
+                            # identical content
+                            n = 1 << (len(pairs) - 1).bit_length()
+                            pairs = pairs + [pairs[0]] * (n - len(pairs))
+                            src = jnp.asarray([s for s, _ in pairs], jnp.int32)
+                            dst = jnp.asarray([d for _, d in pairs], jnp.int32)
+                            state = self._cow(state, src, dst)
+                    clear_preempted_flags()  # CoW-guard preemptions, pre-chunk
+                    if stalled:
+                        note_stall("no free page for CoW materialization")
+                        clock += T
+                        continue
+
+                    if self.paged:
+                        # sample occupancy at the busiest point of the boundary
+                        # — the end-of-run snapshot is always fully drained
+                        s = self.pager.stats()
+                        if peak_pages is None or \
+                                s["pages_used"] > peak_pages["pages_used"]:
+                            peak_pages = s
+
+                # ---- transient step faults (chaos): retry with backoff ------
+                # injected BEFORE the device dispatch (the chunk's state arg is
+                # donated — a post-dispatch replay would reuse consumed
+                # buffers) and BEFORE the rng split, so retried boundaries
+                # consume no randomness and survivors stay bit-identical to a
+                # clean run; then chaos poisons the logits of its chosen rows
+                # for the NaN quarantine below
+                if inj is not None:
+                    with telemetry_mod.phase_timer(None, None, name="chaos"):
+                        attempt, aborted = 0, False
+                        while True:
+                            try:
+                                inj.check_step(st["decode_chunks"])
+                                break
+                            except chaos_mod.InjectedFault as e:
+                                attempt += 1
+                                st["step_retries"] += 1
+                                m.count("step_retries")
+                                limit = g.max_step_retries if g is not None \
+                                    else 3
+                                if attempt > limit:
+                                    reason = (f"decode step failing "
+                                              f"persistently ({e}) — "
+                                              f"{limit} retries spent")
+                                    for row in list(active):
+                                        evict_active(row, "failed", reason)
+                                    for r in list(waiting) + list(pending):
+                                        resolve(r, "failed", reason)
+                                    waiting.clear()
+                                    pending.clear()
+                                    aborted = True
+                                    break
+                                time.sleep(backoff_delay(
+                                    attempt,
+                                    g.backoff_s if g is not None else 0.0))
+                        if aborted:
+                            clear_preempted_flags()
+                            continue
+                        prids = set(inj.nan_rids_for(st["decode_chunks"]))
+                        prows = [row for row, r in active.items()
+                                 if r.rid in prids]
+                        if prows:
+                            cache_c, last_c = state[0], state[1]
+                            last_c = last_c.at[jnp.asarray(prows)].set(jnp.nan)
+                            state = (cache_c, last_c) + state[2:]
+
+                # ---- NaN quarantine (pre-chunk): state[1] holds the logits
+                # the previous chunk (or prefill) produced for each row — a
+                # non-finite value there means this row's next sampled token
+                # would be garbage. Sweep at the boundary, evict poisoned rows
+                # BEFORE dispatching the chunk, so they emit nothing. (Chaos
+                # poisons the same buffer, so injection and genuine NaNs take
+                # the identical detection path. In-scan NaNs are caught one
+                # boundary late — tokens of the chunk that produced them may
+                # include garbage; the terminal outcome says so.)
+                if g is not None and (g.nan_check or inj is not None):
+                    with telemetry_mod.phase_timer(None, None,
+                                                   name="nan_check"):
+                        bad = jax.device_get(jnp.isnan(
+                            state[1]).reshape(self.rows, -1).any(axis=1))
+                        for row in [int(i) for i in np.nonzero(bad)[0]
+                                    if int(i) in active]:
+                            r = active[row]
+                            evict_active(row, "failed",
+                                         "non-finite logits at the sync "
+                                         f"boundary; {len(r.out)} tokens kept")
+                        clear_preempted_flags()
+                        if not active:
+                            st["idle_steps"] += T
+                            clock += T
+                            continue
+
+                # ---------------------- device-resident decode chunk ---------
+                # under speculation the chunk runs against CoW forks of each
+                # row's page chain (refcount++, zero copies): draft writes land
+                # in the fork's tail headroom, commit adopts the fork table
+                # after the device round-trip, and any abort between simply
+                # drops the refcounts — no rollback scatter
+                # fork child ids live at -2 - rid: real rids are >= 0 and -1 is
+                # the empty-device-row sentinel in row_rids, so ~0 == -1 would
+                # hand a dead row the fork's page table and let its flattened
+                # verify writes clobber the parent's KV
+                fork_rids: List[int] = []
+                if self.spec_on:
+                    with telemetry_mod.phase_timer(None, None, name="cow"):
+                        for row in list(admit_order):
+                            if row in active:
+                                rid = active[row].rid
+                                self.pager.fork_chain(rid, -2 - rid)
+                                fork_rids.append(rid)
+                with telemetry_mod.phase_timer(
+                        st, "decode_s", tracer=tr, name="decode_chunk",
+                        start=clock, end=clock + T, slot=slot) as ph:
+                    rng, k = jax.random.split(rng)
+                    bt = self._block_table(row_rids) if self.paged else \
+                        jnp.zeros((self.rows, 1), jnp.int32)
+                    state, toks, emits = self._chunk(self.params, state, k, bt)
+                    with telemetry_mod.phase_timer(None, None,
+                                                   name="decode_wait"):
+                        toks_h, emits_h, live_h = jax.device_get(
+                            (toks, emits, state[3]))
+                    ph.note(rows=len(active))
+                with telemetry_mod.phase_timer(None, None, name="harvest"):
+                    for rid in fork_rids:
+                        self.pager.commit_fork(rid, -2 - rid)
+                    self.host_syncs += 1
+                    st["decode_chunks"] += 1
+                    st["decode_steps"] += T
+                    m.count("decode_chunks")
+                    m.count("decode_steps", T)
+                    stall_streak = 0
+                    clock += T
+                    # window-end gauges, sampled while this chunk's rows are
+                    # still resident (pre-eviction) — the per-window occupancy
+                    # record the plan-drift detector measures against
+                    m.gauge("queue_pending", len(pending))
+                    m.gauge("queue_waiting", len(waiting))
+                    m.gauge("active_rows", len(active))
+                    if self.paged:
+                        self.pager.observe(m)
+                    m.end_window(clock, slot)
+                    emitted = 0
+                    spec = emits_h.ndim == 3    # (T, B, k) speculative chunk
+                    if spec:
+                        for t in range(emits_h.shape[0]):
+                            for row, r in active.items():
+                                for i in range(emits_h.shape[2]):
+                                    if emits_h[t, row, i]:
+                                        tok = int(toks_h[t, row, i])
+                                        r.out.append(tok)
+                                        emitted += 1
+                                        if r.first_token_at is None:
+                                            r.first_token_at = \
+                                                clock - T + t + 1
+                                            r.first_token_wall = \
+                                                time.monotonic()
+                                        if r.on_token is not None:
+                                            r.on_token(r, tok)
+                        drafted = emits_h.shape[0] * emits_h.shape[2] \
+                            * len(active)
+                        st["spec_rounds"] += emits_h.shape[0]
+                        st["spec_drafted_tokens"] += drafted
+                        st["spec_accepted_tokens"] += emitted
+                        m.count("spec_rounds", emits_h.shape[0])
+                        m.count("spec_drafted_tokens", drafted)
+                        m.count("spec_accepted_tokens", emitted)
+                        tr.event("spec_chunk", clock, cat="spec", slot=slot,
+                                 drafted=drafted, accepted=emitted)
+                    else:
+                        for t in range(emits_h.shape[0]):
+                            for row, r in active.items():
+                                if emits_h[t, row]:
+                                    tok = [int(v) for v in toks_h[t, row]] \
+                                        if K > 1 else int(toks_h[t, row])
+                                    r.out.append(tok)
+                                    emitted += 1
+                                    if r.first_token_at is None:
+                                        r.first_token_at = clock - T + t + 1
+                                        r.first_token_wall = time.monotonic()
+                                    if r.on_token is not None:
+                                        r.on_token(r, tok)
+                    m.count("tokens_emitted", emitted)
+                    if getattr(self.plan, "sharded", False):
+                        # analytic collective traffic for this chunk:
+                        # counted under the frozen collective_* keys so
+                        # drift detection can compare measured all-gather
+                        # bytes per token against the mesh decision's model
+                        cc = shard_mod.chunk_collectives(self.plan, steps=T,
+                                                         tokens=emitted)
+                        for key, val in cc.items():
+                            m.count(key, val)
+                        if cc:
+                            tr.event("collective_chunk", clock,
+                                     cat="collective", slot=slot, **cc)
+                    freed_rows: List[int] = []
+                    for row in list(active):
+                        # mirror the device pos: baseline rows advance one per
+                        # scan step; speculative rows advance by their
+                        # accepted count
+                        row_pos[row] += int(emits_h[:, row, :].sum()) \
+                            if spec else T
+                        if not live_h[row]:
+                            r = active.pop(row)
+                            freed_rows.append(row)
+                            admit_order.remove(row)
+                            row_rids[row] = -1
+                            row_pos.pop(row, None)
+                            if self.paged:
+                                self.pager.free(r.rid)   # pages return now
+                            resolve(r, "ok")
+                    alloc.free_many(freed_rows)
+
+                if g is not None and g.audit_every_sync and self.paged:
+                    # debug/CI mode: the full pool invariant audit after every
+                    # sync window — leaks surface at the boundary that caused
+                    # them, not as an end-of-run mystery
+                    with telemetry_mod.phase_timer(None, None, name="audit"):
+                        guard_mod.assert_pool_clean(self.pager, tracer=tr,
+                                                    clock=clock, slot=slot)
         st["total_wall_s"] = run_clock.elapsed_s()
         st["clock_steps"] = clock
         m.gauge("clock", clock)

@@ -37,6 +37,17 @@ construction:
 used to hand-roll three times over), and :func:`heartbeat_record` is the
 shared heartbeat schema (monotonic + wall time, injectable for tests) the
 train-loop Supervisor writes.
+
+One phase taxonomy serves both clocks. Every :func:`phase_timer` block is
+also a profiler span ``serve.<name>`` (``jax.profiler.TraceAnnotation``),
+so with a profiler trace running the host's phases sit on the device
+trace's clock; a phase the :class:`Tracer` records (``prefill``,
+``decode_chunk``) has the same name in both. The scheduler's boundary
+(``serve.boundary``: the generator's resume to its next yield) holds the
+children ``admit``, ``prefill``, ``cow``, ``chaos``, ``nan_check``,
+``decode_chunk`` (with ``decode_wait``, the host's wait for the chunk's
+tokens), ``harvest`` and ``audit``; ``inject`` is its own span. DESIGN.md
+§15 lists what each covers.
 """
 from __future__ import annotations
 
@@ -163,6 +174,9 @@ class Tracer:
 
 
 # ------------------------------------------------------------ phase timing
+SPAN_PREFIX = "serve."          # profiler spans of the serving phases
+
+
 class PhaseHandle:
     """Yielded by :func:`phase_timer`. ``ready(x)`` registers a device value
     to block on before the clock stops (phase-accurate timing for async
@@ -194,27 +208,34 @@ def phase_timer(sink: Optional[Dict], key: Optional[str], *,
     ``[start, end]`` virtual steps with the wall time as an annotation.
     Call ``handle.ready(device_value)`` inside the block to make the timer
     block on async device work before stopping the clock.
+
+    The block, the wait on ``ready`` included, runs under the profiler span
+    ``serve.<name or key>``: on the device trace's clock while a profiler
+    trace runs, a few microseconds of host time when none does.
     """
+    from jax.profiler import TraceAnnotation   # this module imports no jax
+    label = name or key or "phase"
     h = PhaseHandle()
-    t0 = time.perf_counter()
-    try:
-        yield h
-    finally:
-        if h._sync is not None and hasattr(h._sync, "block_until_ready"):
-            h._sync.block_until_ready()
-        h.elapsed_s = time.perf_counter() - t0
-        if sink is not None and key:
-            sink[key] = sink.get(key, 0.0) + h.elapsed_s
-        if tracer is not None:
-            tracer.span(name or key or "phase", start,
-                        start if end is None else end, cat=cat, slot=slot,
-                        rid=rid, wall_s=h.elapsed_s, **h._args)
+    with TraceAnnotation(SPAN_PREFIX + label):
+        t0 = time.perf_counter()
+        try:
+            yield h
+        finally:
+            if h._sync is not None and hasattr(h._sync, "block_until_ready"):
+                h._sync.block_until_ready()
+            h.elapsed_s = time.perf_counter() - t0
+            if sink is not None and key:
+                sink[key] = sink.get(key, 0.0) + h.elapsed_s
+            if tracer is not None:
+                tracer.span(label, start, start if end is None else end,
+                            cat=cat, slot=slot, rid=rid, wall_s=h.elapsed_s,
+                            **h._args)
 
 
 class RunClock:
     """Wall clock for a whole run (the third hand-rolled pattern): started
-    at construction, read via :meth:`elapsed_s` for ``finished_wall_s`` /
-    ``total_wall_s`` stamps — annotations, never part of trace identity."""
+    at construction, read via :meth:`elapsed_s` for the ``total_wall_s``
+    stamp — an annotation, never part of trace identity."""
 
     def __init__(self):
         self.t0 = time.perf_counter()

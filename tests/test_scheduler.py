@@ -183,7 +183,8 @@ def test_scheduler_arrival_gating_and_latency_stamps():
     for r in done:
         assert r.first_token_at > r.admitted_at - 1e-9
         assert r.finished_at >= r.first_token_at
-        assert r.finished_wall_s > 0
+        assert r.queued_wall <= r.admitted_wall <= r.first_token_wall \
+            <= r.finished_wall
 
 
 def test_scheduler_idle_jump_to_next_arrival():
