@@ -289,7 +289,7 @@ def mlp_bound_analysis(arch: str = "qwen2.5-3b", sparsity: float = 0.75,
 
 # ---------------------------------- ISSUE 3: paged KV + continuous batching
 def paged_proxy(arch: str = "qwen2.5-3b-reduced", rows: int = 8,
-                cache_len: int = 512, page_size: int = 64,
+                cache_len: int = 4096, page_size: int = 64,
                 mean_occupancy: float = 0.5, seed: int = 0) -> Dict:
     """Wall-clock-free paged-vs-dense cost model (perf_guard gates these).
 
@@ -298,14 +298,19 @@ def paged_proxy(arch: str = "qwen2.5-3b-reduced", rows: int = 8,
       expected occupancy (pages covering each row's page-rounded length at
       ``mean_occupancy``). Paged must be strictly smaller — that is the
       entire point of block-table indirection.
-    * **grid steps** — the paged decode kernel does real work (DMA + MACs)
-      on exactly ceil(len/ps) steps per row (the pl.when skip,
-      kernels.paged_attention.work_steps); the padded (rows × max_pages)
-      grid and the dense-slot equivalent are reported for the skip ratio.
+    * **blocks** — the paged decode kernel walks blocks of
+      ``pages_per_block`` pages, exactly ceil(ceil(len/ps) / ppb) per row
+      (the trip count of its block loop, kernels.paged_attention.row_blocks);
+      the padded (rows × max_pages / ppb) grid is reported for the skip
+      ratio. ``cache_len`` is the chat cell's 4096, where a row spans up to
+      four blocks.
     """
+    import importlib
+
+    import jax.numpy as jnp
     from repro.core import dataflow
-    from repro.kernels.paged_attention import work_steps
     from repro.serve import kvcache
+    paged = importlib.import_module("repro.kernels.paged_attention")
 
     cfg = get_config(arch)
     rng = np.random.default_rng(seed)
@@ -320,6 +325,9 @@ def paged_proxy(arch: str = "qwen2.5-3b-reduced", rows: int = 8,
     paged_bytes = kvcache.paged_cache_bytes(cfg, rows, cache_len, ceil_pages,
                                             page_size)
     max_pages = dataflow.pages_for(cache_len, page_size)
+    ppb = paged.pages_per_block(page_size, cfg.num_kv_heads, cfg.head_dim,
+                                jnp.dtype(decoding.COMPUTE_DTYPE).itemsize,
+                                max_pages)
     return {
         "arch": arch, "rows": rows, "cache_len": cache_len,
         "page_size": page_size, "mean_occupancy": mean_occupancy,
@@ -327,11 +335,16 @@ def paged_proxy(arch: str = "qwen2.5-3b-reduced", rows: int = 8,
         "dense_slot_bytes": dense_bytes,
         "paged_bytes": paged_bytes,
         "bytes_ratio": dense_bytes / max(paged_bytes, 1),
-        # the kernel's own skip bound (kernels.paged_attention.row_work_steps,
-        # the expression its pl.when evaluates) vs. the spec bound above
-        "work_steps": work_steps(lengths, page_size),
+        "pages_per_block": ppb,
+        # the kernel's own skip bound (kernels.paged_attention.row_blocks,
+        # the trip count of its block loop) vs. the spec bound from
+        # core.dataflow's pages
+        "work_blocks": int(paged.row_blocks(np.asarray(lengths), 0, page_size,
+                                            ppb, max_pages).sum()),
+        "ceil_blocks": sum(-(-dataflow.pages_for(n, page_size) // ppb)
+                           for n in lengths),
+        "padded_grid_blocks": rows * -(-max_pages // ppb),
         "ceil_pages": ceil_pages,
-        "padded_grid_steps": rows * max_pages,
         "tokens_resident_paged": dataflow.paged_kv_tokens(lengths, page_size),
         "tokens_resident_dense": dataflow.dense_kv_tokens(rows, cache_len),
     }
@@ -1346,8 +1359,9 @@ def main(smoke: bool = False, engine: bool = True, repeats: int = None,
     print(f"  dense slot {pg['dense_slot_bytes']:9d} B  "
           f"paged {pg['paged_bytes']:9d} B  "
           f"({pg['bytes_ratio']:.2f}x smaller)")
-    print(f"  kernel work steps {pg['work_steps']} <= ceil-pages "
-          f"{pg['ceil_pages']} (padded grid {pg['padded_grid_steps']})")
+    print(f"  kernel blocks {pg['work_blocks']} <= ceil-blocks "
+          f"{pg['ceil_blocks']} (padded grid {pg['padded_grid_blocks']}, "
+          f"{pg['pages_per_block']} pages a block)")
 
     if "arrivals" in res:
         ar = res["arrivals"]

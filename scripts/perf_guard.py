@@ -15,8 +15,9 @@ Checks:
   5. the batch-1 e2e ratio and per-phase breakdown are present (the
      benchmark actually measured what the JSON claims)
   6. paged KV (ISSUE 3): paged cache bytes strictly below the dense slot
-     cache at 50% mean occupancy, and the paged decode kernel's work steps
-     within ceil(len/page_size) per row (the pl.when skip bound)
+     cache at 50% mean occupancy, and the paged decode kernel's blocks
+     within ceil(ceil(len/page_size) / pages_per_block) per row (its block
+     loop's trip count) and below the padded grid
   7. arrivals (ISSUE 3): continuous batching beats the drain-the-chunk
      baseline on goodput at high length variance — gated because both sides
      run on the deterministic virtual step clock, not wall time
@@ -121,17 +122,18 @@ def main(path: str = "BENCH_sparse_decode.json") -> int:
               f"paged {pg['paged_bytes']} < dense slot "
               f"{pg['dense_slot_bytes']} at {pg['mean_occupancy']:.0%} "
               f"occupancy ({pg['bytes_ratio']:.2f}x)")
-        # work_steps comes from the kernel's own skip expression
-        # (kernels.paged_attention.row_work_steps — shared with its pl.when
-        # guard), ceil_pages from core.dataflow: a kernel-side skip
+        # work_blocks comes from the kernel's own skip expression
+        # (kernels.paged_attention.row_blocks — the trip count of its block
+        # loop), ceil_blocks from core.dataflow's pages: a kernel-side skip
         # regression moves the left side and trips one of these
-        check("paged-grid-steps", pg["work_steps"] <= pg["ceil_pages"],
-              f"kernel work steps {pg['work_steps']} <= spec ceil(len/ps) "
-              f"sum {pg['ceil_pages']}")
+        check("paged-grid-steps", pg["work_blocks"] <= pg["ceil_blocks"],
+              f"kernel blocks {pg['work_blocks']} <= spec "
+              f"ceil(ceil(len/ps)/{pg['pages_per_block']}) sum "
+              f"{pg['ceil_blocks']}")
         check("paged-skip-saves-steps",
-              pg["work_steps"] < pg["padded_grid_steps"],
-              f"kernel work steps {pg['work_steps']} < padded grid "
-              f"{pg['padded_grid_steps']} (ragged rows must skip)")
+              pg["work_blocks"] < pg["padded_grid_blocks"],
+              f"kernel blocks {pg['work_blocks']} < padded grid "
+              f"{pg['padded_grid_blocks']} (ragged rows must skip)")
     else:
         print("  [--] paged section absent; paged gates skipped")
 

@@ -41,6 +41,7 @@ scheduler replaces that model end to end:
 from __future__ import annotations
 
 import dataclasses
+import importlib
 import time
 import warnings
 from typing import Callable, Dict, List, Optional, Tuple
@@ -58,6 +59,10 @@ from repro.serve import guard as guard_mod
 from repro.serve import telemetry as telemetry_mod
 from repro.serve.engine import (build_tier_batch, make_decode_step,
                                 make_spec_decode_step)
+
+# the kernel module itself: repro.kernels re-exports a wrapper function
+# under the module's name
+paged_kernel = importlib.import_module("repro.kernels.paged_attention")
 
 
 @dataclasses.dataclass
@@ -429,6 +434,35 @@ class ContinuousBatchingScheduler:
         """Upper bound on tokens this request ever holds (page cap)."""
         return len(r.prompt) + r.max_new
 
+    def _attn_blocks(self, starts, accepted=None) -> Tuple[int, int]:
+        """Blocks of pages the paged attention kernel walks in one decode
+        chunk, and those a (rows, max_pages / pages_per_block) grid would
+        visit, each summed over the chunk's steps, paged layers and tp
+        shards. ``starts`` holds the device pos at dispatch of each row that
+        holds pages; ``accepted`` (T, rows) the tokens each speculative
+        round retired per row, None for one token per step. Rows without
+        pages walk nothing, as the kernel's ``row_blocks`` says."""
+        cfg, T = self.cfg, self.sync_every
+        tp = getattr(self.plan, "tp", 1) or 1
+        itemsize = 1 if self.kv_quant == "int8" else \
+            jnp.dtype(decoding.COMPUTE_DTYPE).itemsize
+        ppb = paged_kernel.pages_per_block(
+            self.page_size, cfg.num_kv_heads // tp, cfg.head_dim, itemsize,
+            self.max_pages)
+        starts = np.asarray(starts, np.int64)[None, :]
+        if accepted is None:
+            k, pos = 1, starts + np.arange(T)[:, None]
+        else:
+            k, acc = self.spec_k, np.asarray(accepted, np.int64)
+            pos = starts + np.cumsum(acc, axis=0) - acc
+        lengths = pos[..., None] + np.arange(1, k + 1)        # (T, rows, k)
+        real = paged_kernel.row_blocks(lengths, 0, self.page_size, ppb,
+                                       self.max_pages).sum()
+        grid = T * self.rows * k * -(-self.max_pages // ppb)
+        calls = tp * sum(cfg.layer_kind(i) == "global"
+                         for i in range(cfg.num_layers))
+        return int(real) * calls, grid * calls
+
     def _block_table(self, row_rids: List[int]):
         return jnp.asarray(self.pager.block_table_rows(row_rids,
                                                        self.max_pages))
@@ -675,6 +709,10 @@ class ContinuousBatchingScheduler:
             "spec_accepted_tokens": 0,     # candidates emitted (greedy-exact)
             "resume_fast_prompts": 0,      # suffix-only recompute resumes
             "resume_fast_tokens": 0,       # prompt tokens NOT re-prefilled
+            # paged attention kernel (kernels.paged_attention): blocks of
+            # pages walked, and those a padded (rows, blocks) grid visits
+            "attn_blocks_real": 0,
+            "attn_blocks_grid": 0,
         }
 
         preempted_rows: List[int] = []
@@ -1259,6 +1297,16 @@ class ContinuousBatchingScheduler:
                     st["decode_steps"] += T
                     m.count("decode_chunks")
                     m.count("decode_steps", T)
+                    if self.paged:
+                        rows_held = list(active)
+                        real, grid = self._attn_blocks(
+                            [row_pos[row] for row in rows_held],
+                            emits_h[:, rows_held].sum(axis=-1)
+                            if emits_h.ndim == 3 else None)
+                        st["attn_blocks_real"] += real
+                        st["attn_blocks_grid"] += grid
+                        m.count("attn_blocks_real", real)
+                        m.count("attn_blocks_grid", grid)
                     stall_streak = 0
                     clock += T
                     # window-end gauges, sampled while this chunk's rows are

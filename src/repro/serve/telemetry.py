@@ -287,6 +287,9 @@ COUNTER_KEYS: Tuple[str, ...] = (
     # mesh collectives (ISSUE 10): analytic all-gather accounting on
     # sharded plans (serve.shard.chunk_collectives); zero on tp=ep=1
     "collective_ops", "collective_allgather_bytes",
+    # paged attention kernel: blocks of pages walked (its row_blocks bound)
+    # and those a padded (rows, max_pages / pages_per_block) grid visits
+    "attn_blocks_real", "attn_blocks_grid",
 )
 GAUGE_KEYS: Tuple[str, ...] = (
     "clock", "queue_pending", "queue_waiting", "active_rows",

@@ -2,6 +2,7 @@
 gather-then-softmax oracle and the contiguous-ring reference, across ragged
 lengths, page boundaries, GQA/softcap, and multi-codebook configs."""
 import dataclasses
+import importlib
 
 import jax
 import jax.numpy as jnp
@@ -13,28 +14,42 @@ from hypothesis_compat import given, settings, st
 from repro.configs import get_config
 from repro.core import dataflow
 from repro.kernels import ops, ref
-from repro.kernels.paged_attention import work_steps
+paged = importlib.import_module("repro.kernels.paged_attention")
 from repro.models import decoding, layers, transformer as tfm
 from repro.serve.paging import PageAllocator
 
 
-def _paged_case(lengths, page_size, KV=2, R=2, D=16, seed=0, dtype=jnp.float32):
-    """Random pools + a permuted block table covering ``lengths``."""
+def _paged_case(lengths, page_size, KV=2, R=2, D=16, seed=0, dtype=jnp.float32,
+                max_pages=None, free_rows=(), quant=False):
+    """Random pools + a permuted block table covering ``lengths``; rows in
+    ``free_rows`` get an all -1 table. ``quant`` makes int8 pools and
+    returns their (P, KV) scales too."""
     rng = np.random.default_rng(seed)
     B = len(lengths)
-    MP = max(dataflow.pages_for(n, page_size) for n in lengths)
+    MP = max_pages or max(dataflow.pages_for(n, page_size) for n in lengths)
     P = sum(dataflow.pages_for(n, page_size) for n in lengths) + 1
     q = jnp.asarray(rng.standard_normal((B, KV, R, D)), dtype)
-    kp = jnp.asarray(rng.standard_normal((P, page_size, KV, D)), dtype)
-    vp = jnp.asarray(rng.standard_normal((P, page_size, KV, D)), dtype)
+    if quant:
+        kp, vp = (jnp.asarray(rng.integers(-127, 128, (P, page_size, KV, D)),
+                              jnp.int8) for _ in range(2))
+    else:
+        kp = jnp.asarray(rng.standard_normal((P, page_size, KV, D)), dtype)
+        vp = jnp.asarray(rng.standard_normal((P, page_size, KV, D)), dtype)
     bt = np.full((B, MP), -1, np.int32)
     perm = rng.permutation(P)        # physical pages deliberately non-contiguous
     i = 0
     for b, n in enumerate(lengths):
+        if b in free_rows:
+            continue
         for j in range(dataflow.pages_for(n, page_size)):
             bt[b, j] = perm[i]
             i += 1
-    return q, kp, vp, jnp.asarray(bt), jnp.asarray(np.asarray(lengths, np.int32))
+    case = (q, kp, vp, jnp.asarray(bt),
+            jnp.asarray(np.asarray(lengths, np.int32)))
+    if quant:
+        case += tuple(jnp.asarray(rng.uniform(0.5, 3.0, (P, KV)), jnp.float32)
+                      for _ in range(2))
+    return case
 
 
 # ------------------------------------------------------------------- kernel
@@ -100,15 +115,74 @@ def test_paged_kernel_matches_contiguous_decode_attention():
 
 
 def test_paged_kernel_work_steps_proxy():
-    """The skip bound: real work on exactly ceil(len/ps) grid steps per row."""
-    ps = 8
-    lengths = [1, 8, 9, 24]
-    assert work_steps(lengths, ps) == 1 + 1 + 2 + 3
-    assert work_steps(lengths, ps) == sum(
-        dataflow.pages_for(n, ps) for n in lengths)
-    # and strictly below the padded grid when rows are ragged
-    MP = max(dataflow.pages_for(n, ps) for n in lengths)
-    assert work_steps(lengths, ps) < len(lengths) * MP
+    """The skip bound, in blocks: a row walks ceil(ceil(len/ps) / ppb)
+    blocks, a row without pages none, and ragged rows walk strictly fewer
+    blocks than the padded (rows, max_pages / ppb) grid."""
+    ps, ppb, MP = 8, 2, 6
+    lengths = np.asarray([1, 8, 9, 17, 40])
+    blocks = paged.row_blocks(lengths, 0, ps, ppb, MP)
+    np.testing.assert_array_equal(blocks, [1, 1, 1, 2, 3])
+    np.testing.assert_array_equal(
+        blocks, [-(-dataflow.pages_for(n, ps) // ppb) for n in lengths])
+    assert blocks.sum() < len(lengths) * -(-MP // ppb)
+    # a free row (table starts unallocated) walks nothing; lengths past
+    # the table clamp to max_pages
+    assert paged.row_blocks(40, -1, ps, ppb, MP) == 0
+    assert paged.row_blocks(10_000, 3, ps, ppb, MP) == -(-MP // ppb)
+    # one expression on ints, numpy arrays and traced scalars alike
+    assert int(jax.jit(lambda n: paged.row_blocks(n, jnp.int32(0), ps, ppb,
+                                                  MP))(jnp.int32(17))) == 2
+
+
+@pytest.mark.parametrize("block_pages", [2, 4])
+@pytest.mark.parametrize("softcap", [0.0, 30.0])
+@pytest.mark.parametrize("quant", [False, True])
+@pytest.mark.parametrize("KV", [1, 2, 8])
+def test_paged_kernel_block_edges(monkeypatch, KV, quant, softcap,
+                                  block_pages):
+    """Blocks of pages: lengths at a block edge and ±1, a max_pages that is
+    not a multiple of the block, -1 entries past occupancy, and a free row
+    (table all -1) that reads zeros; fp and int8 pools, softcap on and off,
+    KV 1, 2 and 8."""
+    ps = 4
+    T = block_pages * ps
+    monkeypatch.setattr(paged, "BLOCK_TOKENS", T)
+    lengths = [T - 1, T, T + 1, 2 * T, 2 * T + 1, T]
+    MP = 2 * block_pages + 1
+    assert paged.pages_per_block(ps, KV, 16, 4, MP) == block_pages
+    case = _paged_case(lengths, ps, KV=KV, seed=KV, max_pages=MP,
+                       free_rows=(5,), quant=quant)
+    q, kp, vp, bt, lens = case[:5]
+    scales = dict(zip(("k_scale", "v_scale"), case[5:]))
+    out = paged.paged_attention_raw(q, kp, vp, bt, lens, softcap=softcap,
+                                    interpret=True, **scales)
+    expect = ref.paged_attention_ref(q, kp, vp, bt, lens, softcap=softcap,
+                                     **scales)
+    np.testing.assert_allclose(np.asarray(out[:5]), np.asarray(expect[:5]),
+                               rtol=1e-5, atol=1e-5)
+    assert not np.asarray(out[5]).any()
+
+
+def test_pages_per_block_follows_shapes():
+    """~1024 tokens a block, halved until two K and two V buffers fit the
+    VMEM budget, capped by what max_pages needs; pages are read head-major
+    only where a token's heads do not fill a 32-bit word."""
+    ppb = paged.pages_per_block
+    assert ppb(64, 2, 128, 1, 64) == 16           # qwen2.5-3b, int8 pool
+    assert ppb(64, 8, 128, 1, 208) == 16          # mistral-nemo, int8 pool
+    assert ppb(64, 8, 128, 2, 208) == 8           # bf16 pool
+    assert ppb(64, 8, 128, 4, 208) == 4           # fp32 pool
+    assert ppb(48, 2, 128, 1, 64) == 16           # power of two below 1024/48
+    assert ppb(4, 2, 16, 4, 3) == 4               # no larger than the table
+    assert ppb(64, 2, 128, 1, 1) == 1
+    for ps, kv, d, item, mp in [(64, 2, 128, 1, 64), (64, 8, 128, 2, 208)]:
+        n = ppb(ps, kv, d, item, mp)
+        assert n & (n - 1) == 0
+        assert 4 * kv * n * ps * d * item <= paged.VMEM_BUDGET
+    assert paged.heads_major(2, 1) and paged.heads_major(1, 1)
+    assert paged.heads_major(1, 2)
+    assert not paged.heads_major(8, 1) and not paged.heads_major(4, 1)
+    assert not paged.heads_major(2, 2) and not paged.heads_major(1, 4)
 
 
 @settings(max_examples=20, deadline=None)

@@ -1,7 +1,10 @@
 """Continuous-batching scheduler + paging subsystem (ISSUE 3): PageAllocator
 accounting, SlotAllocator batch ops, the streaming scheduler vs the
 DecodeEngine reference, arrivals, EOS page return, and recompute preemption."""
+import importlib
+
 import jax
+import numpy as np
 import pytest
 
 from repro.configs import get_config
@@ -140,6 +143,44 @@ def test_scheduler_matches_engine_tokens(attn_path):
     got = [r.out for r in sorted(done, key=lambda r: r.rid)]
     assert got == ref
     assert sch.phase_stats["attn_path"] == attn_path
+
+
+@pytest.mark.parametrize("kv_quant", ["fp", "int8"])
+def test_scheduler_attn_block_counters_match_kernel(monkeypatch, kv_quant):
+    """attn_blocks_real / attn_blocks_grid equal what the paged kernel was
+    handed: its own row_blocks bound over the lengths and tables of every
+    call, and the padded (rows, blocks) grid of those calls."""
+    kmod = importlib.import_module("repro.kernels.paged_attention")
+    monkeypatch.setattr(kmod, "BLOCK_TOKENS", 8)      # 2 pages of 4 a block
+    real_raw = kmod.paged_attention_raw
+    seen = []
+
+    def recording(q, k_pool, v_pool, block_table, lengths, **kw):
+        P, ps, KV, D = k_pool.shape
+        MP = block_table.shape[1]
+        ppb = kmod.pages_per_block(ps, KV, D, k_pool.dtype.itemsize, MP)
+
+        def record(lens, first):
+            seen.append((int(kmod.row_blocks(lens, first, ps, ppb, MP).sum()),
+                         len(lens) * -(-MP // ppb)))
+        jax.debug.callback(record, lengths, block_table[:, 0])
+        return real_raw(q, k_pool, v_pool, block_table, lengths, **kw)
+
+    monkeypatch.setattr(kmod, "paged_attention_raw", recording)
+    cfg = get_config("qwen2.5-3b-reduced")
+    params = tfm.init_params(jax.random.PRNGKey(0), cfg)
+    sch = ContinuousBatchingScheduler(cfg, params, rows=2, cache_len=32,
+                                      page_size=4, eos_id=-1, sync_every=4,
+                                      attn_path="paged", kv_quant=kv_quant)
+    prompts = [[5] * 3, [7] * 13, [2] * 6]
+    sch.run([StreamRequest(i, p, 9) for i, p in enumerate(prompts)])
+    jax.effects_barrier()
+    st = sch.phase_stats
+    assert st["attn_blocks_real"] == sum(r for r, _ in seen) > 0
+    assert st["attn_blocks_grid"] == sum(g for _, g in seen)
+    assert st["attn_blocks_real"] < st["attn_blocks_grid"]
+    assert sch.telemetry.metrics.counters["attn_blocks_real"] == \
+        st["attn_blocks_real"]
 
 
 def test_scheduler_recurrent_arch_contiguous_fallback():

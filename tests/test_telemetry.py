@@ -162,7 +162,7 @@ def test_metric_key_set_is_frozen():
     m = telemetry.MetricsRegistry()
     snap = m.snapshot()
     assert snap.key_set() == telemetry.METRIC_KEYS
-    assert len(telemetry.COUNTER_KEYS) == 31
+    assert len(telemetry.COUNTER_KEYS) == 33
     assert len(telemetry.GAUGE_KEYS) == 12
     assert len(telemetry.HISTOGRAM_KEYS) == 5
     # mesh-sharded serving (ISSUE 10): the shard/collective keys are part
@@ -170,6 +170,8 @@ def test_metric_key_set_is_frozen():
     # (test_registry_rejects_undeclared_names), not silently appear
     assert "collective_ops" in telemetry.COUNTER_KEYS
     assert "collective_allgather_bytes" in telemetry.COUNTER_KEYS
+    assert "attn_blocks_real" in telemetry.COUNTER_KEYS
+    assert "attn_blocks_grid" in telemetry.COUNTER_KEYS
     assert "shard_pages_used_max" in telemetry.GAUGE_KEYS
     assert "shard_pages_used_min" in telemetry.GAUGE_KEYS
     assert "shard_lockstep_divergence" in telemetry.GAUGE_KEYS

@@ -3,8 +3,9 @@
 Interpret mode cannot see the TPU's tiling rules or its memory limits; the
 TPU compiler can, for a chip it is only told about. Every case here compiles
 the raw kernel with ``interpret=False`` at a published width: qwen2.5-3b
-(GQA 16/2, head_dim 128, 64-token pages) and a gemma-like head_dim 256 with
-a logit softcap. The BCSC sparse kernels are held as strict expected
+(GQA 16/2, head_dim 128, 64-token pages; and one tp=2 shard of it, one KV
+head), mistral-nemo (GQA 32/8, pages read token-major) and a gemma-like
+head_dim 256 with a logit softcap. The BCSC sparse kernels are held as strict expected
 failures with the compiler's reason, so a fix that makes them compile flips
 these tests (ROADMAP Design item 7 decides whether they stay).
 
@@ -50,7 +51,9 @@ def _compile(fn, *args):
 # ---------------------------------------------------------- paged attention
 @pytest.mark.parametrize("quant", ["fp", "int8"])
 @pytest.mark.parametrize("KV,R,D,softcap", [(2, 8, 128, 0.0),
-                                            (4, 2, 256, 50.0)])
+                                            (4, 2, 256, 50.0),
+                                            (8, 4, 128, 0.0),
+                                            (1, 8, 128, 0.0)])
 def test_paged_attention_compiles_for_v5e(spec, quant, KV, R, D, softcap):
     B, ps, MP, P = 16, 64, 64, 1024
     pool = jnp.int8 if quant == "int8" else jnp.bfloat16
