@@ -1,102 +1,18 @@
-"""The configuration as it is run, and its weights.
-
-``program_config`` turns a configuration file (the keys of the model's
-public ``config.json``, depth as cut) into the program's ``ArchConfig``:
-every size in the file is set on the registry's entry, so the program runs
-as the file states. ``make_params`` makes the weights from the seed on the
-device, in one jitted call, in bf16 as they are served, in the layout the
-program reads. ``plain_weights`` hands the same arrays to the reference
-under plain names, so the reference needs nothing of the program.
+"""The weights every block shares: ``make_params`` makes them from the seed
+on the device, in one jitted call, in bf16 as they are served, in the
+layout the program reads (its abstract parameter tree). What a block adds
+(its sizes, its ``ArchConfig`` and the reference's view of these arrays)
+lives in ``bench/blocks/<block>.py``.
 """
 from __future__ import annotations
 
-import dataclasses
 import functools
-from typing import Dict
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-# configuration-file key -> ArchConfig field
-FIELDS = {
-    "num_hidden_layers": "num_layers",
-    "hidden_size": "d_model",
-    "num_attention_heads": "num_heads",
-    "num_key_value_heads": "num_kv_heads",
-    "head_dim": "head_dim",
-    "intermediate_size": "d_ff",
-    "vocab_size": "vocab_size",
-    "rope_theta": "rope_theta",
-    "rms_norm_eps": "norm_eps",
-    "tie_word_embeddings": "tie_embeddings",
-}
 NORM_OFFSET_STD = 0.05       # norm gains 1 + N(0, 0.05): the gain is used
-
-
-@dataclasses.dataclass(frozen=True)
-class Dims:
-    """The plain sizes the reference and the yardstick read."""
-    layers: int
-    d: int
-    heads: int
-    kv_heads: int
-    head_dim: int
-    d_ff: int
-    vocab: int
-    tied: bool
-    rope_theta: float
-    eps: float
-    qkv_bias: bool
-
-    @classmethod
-    def from_file(cls, c: Dict) -> "Dims":
-        return cls(layers=c["num_hidden_layers"], d=c["hidden_size"],
-                   heads=c["num_attention_heads"],
-                   kv_heads=c["num_key_value_heads"],
-                   head_dim=c.get("head_dim",
-                                  c["hidden_size"] // c["num_attention_heads"]),
-                   d_ff=c["intermediate_size"], vocab=c["vocab_size"],
-                   tied=bool(c["tie_word_embeddings"]),
-                   rope_theta=float(c["rope_theta"]),
-                   eps=float(c["rms_norm_eps"]),
-                   qkv_bias=bool(c["assumed"]["qkv_bias"]))
-
-    def layer_matmul_params(self) -> int:
-        """Weights one layer multiplies a token by (q, k, v, o, gate, up,
-        down)."""
-        d, hd = self.d, self.head_dim
-        return (d * self.heads * hd * 2 + d * self.kv_heads * hd * 2
-                + 3 * d * self.d_ff)
-
-    def weight_bytes(self, vocab_rows: int) -> int:
-        """bf16 bytes of every weight, with ``vocab_rows`` rows of the
-        embedding (and of the head, where it is not tied)."""
-        d, hd = self.d, self.head_dim
-        per_layer = self.layer_matmul_params() + 2 * d
-        if self.qkv_bias:
-            per_layer += (self.heads + 2 * self.kv_heads) * hd
-        embed = vocab_rows * d * (1 if self.tied else 2)
-        return 2 * (self.layers * per_layer + embed + d)
-
-
-def program_config(c: Dict):
-    """The program's ``ArchConfig`` for configuration file ``c``."""
-    from repro.configs import get_config
-    base = get_config(c["registry"])
-    repl = {}
-    for key, field in FIELDS.items():
-        if key == "head_dim" and key not in c:
-            continue
-        repl[field] = type(getattr(base, field))(c[key])
-    arch = dataclasses.replace(base, **repl)
-    if not (arch.mlp_act == c["hidden_act"] and arch.mlp_gated
-            and arch.qkv_bias == c["assumed"]["qkv_bias"]
-            and arch.attn_pattern == ("global",)):
-        raise ValueError(f"{c['registry']}: the program's block is not the "
-                         "configuration's (gated silu MLP, global attention)")
-    arch.validate()
-    return arch
 
 
 def _path_str(path) -> str:
@@ -132,24 +48,3 @@ def make_params(arch, seed_key, init_range: float):
                    for p, l in flat)
     leaves = _init(seed_key, shapes, arch.vocab_size, float(init_range))
     return jax.tree_util.tree_unflatten(tree, leaves)
-
-
-def plain_weights(params) -> Dict:
-    """The program's parameter tree under plain names, without a copy.
-
-    The program keeps each layer's arrays stacked on a leading layer axis
-    under ``blocks/slot0`` and stores a norm gain g as g - 1; the
-    reference reads ``ln*`` as that offset and adds 1 itself."""
-    b = params["blocks"]["slot0"]
-    a, m = b["attn"], b["mlp"]
-    layers = {"ln1": b["pre_norm"], "ln2": b["pre_norm_mlp"],
-              "wq": a["wq"], "wk": a["wk"], "wv": a["wv"], "wo": a["wo"],
-              "wg": m["wg"], "wu": m["wu"], "wd": m["wd"]}
-    for k in ("bq", "bk", "bv"):
-        if k in a:
-            layers[k] = a[k]
-    out = {"embed": params["embed"], "final_norm": params["final_norm"],
-           "layers": layers}
-    if "lm_head" in params:
-        out["lm_head"] = params["lm_head"]
-    return out

@@ -12,12 +12,11 @@ from typing import List, Optional, Sequence, Tuple
 from benchlib import roofline, trace as trace_lib
 
 # names of the program's device work, as its trace shows them: the jitted
-# decode chunk and prefill programs, and the Mosaic kernel that the decode
-# chunk calls (the paged attention kernel is its only Pallas kernel on
-# these cells' path; the trace does not name kernels)
+# decode chunk and prefill programs, and the ``pallas_call`` name of the
+# paged decode-attention kernel that the decode chunk calls
 DECODE_PROGRAM = "jit_chunk"
 PREFILL_PROGRAM = "jit_refill"
-DECODE_KERNEL = "tpu_custom_call"
+DECODE_KERNEL = "paged_attention"
 
 
 @dataclasses.dataclass
@@ -27,7 +26,7 @@ class Record:
     reqs: list                            # serve.RunReq, every request
     boundaries: list                      # serve.Boundary, every boundary
     counted: list                         # the requests the window judges
-    dims: object                          # model.Dims
+    dims: object                          # the block's Dims
     rows: int
     page_size: int
     kv_bytes: int                         # bytes per cached element
@@ -96,12 +95,14 @@ def chunk_rows(rec: Record) -> List[int]:
 
 
 # ---------------------------------------------------------- device trace
-def _events(rec: Record, which: str, needle: str):
+def _events(rec: Record, which: str, name: str):
+    """Events of the traced window: the programs (``"modules"``) whose name
+    holds ``name``, or the calls of the kernel ``name`` (``"kernels"``)."""
     if rec.trace is None:
         return []
-    evs = rec.trace.modules if which == "modules" else rec.trace.ops
-    return trace_lib.in_window(trace_lib.matching(evs, needle),
-                               rec.trace_window)
+    evs = (trace_lib.kernel_calls(rec.trace.ops, name) if which == "kernels"
+           else trace_lib.matching(rec.trace.modules, name))
+    return trace_lib.in_window(evs, rec.trace_window)
 
 
 def device_s(rec: Record, which: str, needle: str) -> Optional[float]:
@@ -128,10 +129,10 @@ def step_mfu(rec: Record) -> Optional[float]:
 
 def paged_attention_roofline(rec: Record) -> Optional[float]:
     """Least time of the kernel's calls in the window over their device
-    time. Each decode step calls it once per layer, over the live rows'
-    resident lengths at that step."""
+    time. Each decode step calls it once per paged layer, over the live
+    rows' resident lengths at that step."""
     pk = roofline.peaks(rec.kind)
-    calls = trace_lib.inside(_events(rec, "ops", DECODE_KERNEL),
+    calls = trace_lib.inside(_events(rec, "kernels", DECODE_KERNEL),
                              _events(rec, "modules", DECODE_PROGRAM))
     spent = sum(d for _, _, d in calls) / 1e9
     steps = [s for s in decode_steps(rec) if s]
@@ -143,10 +144,13 @@ def paged_attention_roofline(rec: Record) -> Optional[float]:
             roofline.paged_attention_flops(rec.dims, s),
             roofline.paged_attention_bytes(rec.dims, s, rec.page_size,
                                            rec.kv_bytes), pk)
-        least += rec.dims.layers * t
+        least += rec.dims.paged_layers * t
         bounds[bound] = bounds.get(bound, 0) + 1
     rec.notes.append("paged_attention roofline bound: " + ", ".join(
-        f"{k} in {v} of {len(steps)} steps" for k, v in sorted(bounds.items())))
+        f"{k} in {v} of {len(steps)} steps" for k, v in sorted(bounds.items()))
+        + f"; {len(calls)} kernel calls in the trace of "
+        f"{len(steps) * rec.dims.paged_layers} steps x paged layers, "
+        f"{spent!r} s")
     return 100.0 * least / spent
 
 

@@ -1,13 +1,15 @@
-"""Plain float32 reference of the served model (Qwen2 / Mistral block).
+"""What every block's plain float32 reference shares.
 
-A decoder of pre-norm blocks as the public model cards describe it: RMSNorm
-(gain ``1 + ln``, see ``model.plain_weights``), grouped-query attention with
-rotary positions on the two halves of each head, optional q/k/v biases, a
-gated SiLU MLP, a final RMSNorm and a tied or separate head. Every matrix
-product runs in float32 at ``Precision.HIGHEST``; nothing is cached, paged,
-batched or quantised. It imports nothing of the program.
+The helpers a block's layer is built from (RMSNorm with gain ``1 + ln``,
+rotary positions on the two halves of each head, blocked causal
+grouped-query attention, the control's rounding) and the decoder around
+the layers: the embedding, the layer loop, a final RMSNorm and a tied or
+separate head. The layer is the block's (``bench/blocks/<block>.py``),
+handed in as ``layer(lw, i, x, dims, quant)``. Every matrix product runs
+in float32 at ``Precision.HIGHEST``; nothing is cached, paged, batched or
+quantised. It imports nothing of the program.
 
-``logits_at`` runs one sequence layer by layer (one jitted call per layer,
+``logits_at`` runs one sequence layer by layer (one call per layer,
 queries in blocks), so that it fits beside the served weights, and returns
 the logits at the positions asked for. ``quant="control"`` is the control:
 the same forward one precision step below what the configuration states,
@@ -84,36 +86,6 @@ def _attention(q, k, v):
     return out.reshape(S, H * D)
 
 
-@functools.partial(jax.jit, static_argnums=(3, 4))
-def _layer(lw, i, x, dims, quant):
-    def w(name):
-        return jax.lax.dynamic_index_in_dim(lw[name], i, 0,
-                                            keepdims=False).astype(jnp.float32)
-
-    S, d = x.shape
-    H, KV, D = dims.heads, dims.kv_heads, dims.head_dim
-    pos = jnp.arange(S)
-    h = _rms(x, w("ln1"), dims.eps)
-    q = _mm(h, w("wq").reshape(d, H * D), quant)
-    k = _mm(h, w("wk").reshape(d, KV * D), quant)
-    v = _mm(h, w("wv").reshape(d, KV * D), quant)
-    if dims.qkv_bias:
-        q = q + w("bq").reshape(-1)
-        k = k + w("bk").reshape(-1)
-        v = v + w("bv").reshape(-1)
-    q = _rope(q.reshape(S, H, D), pos, dims.rope_theta)
-    k = _rope(k.reshape(S, KV, D), pos, dims.rope_theta)
-    v = v.reshape(S, KV, D)
-    if quant == "control":
-        k, v = _fake_int(k, -1, 7.0), _fake_int(v, -1, 7.0)
-    ctx = _attention(q, k, v)
-    x = x + _mm(ctx, w("wo").reshape(H * D, d), quant)
-    h = _rms(x, w("ln2"), dims.eps)
-    gate = jax.nn.silu(_mm(h, w("wg"), quant))
-    x = x + _mm(gate * _mm(h, w("wu"), quant), w("wd"), quant)
-    return x
-
-
 @functools.partial(jax.jit, static_argnums=(2,))
 def _embed(table, tokens, dims):
     return table[tokens].astype(jnp.float32)
@@ -129,16 +101,17 @@ def _head(wts, x, rows, dims, quant):
     return _mm(h, table, quant)
 
 
-def logits_at(wts, dims, tokens, positions, quant=None):
+def logits_at(layer, wts, dims, tokens, positions, quant=None):
     """Logits (len(positions), vocab) of the sequence ``tokens`` at
-    ``positions`` (each the position whose next token they predict)."""
+    ``positions`` (each the position whose next token they predict),
+    through the block's ``layer`` at each of ``dims.layers``."""
     tokens = np.asarray(tokens, np.int32)
     S = bucket(len(tokens))
     padded = np.zeros((S,), np.int32)
     padded[:len(tokens)] = tokens
     x = _embed(wts["embed"], jnp.asarray(padded), dims)
     for i in range(dims.layers):
-        x = _layer(wts["layers"], jnp.int32(i), x, dims, quant)
+        x = layer(wts["layers"], jnp.int32(i), x, dims, quant)
     head = {k: wts[k] for k in ("embed", "final_norm", "lm_head") if k in wts}
     n = len(positions)
     rows = np.zeros((-(-n // 256) * 256,), np.int32)     # few head shapes
@@ -159,7 +132,7 @@ def gaps(ref_logits, tokens) -> np.ndarray:
     return ref.max(axis=-1) - ref[np.arange(len(tokens)), tokens]
 
 
-def request_gaps(wts, dims, prompt, served, quant=None) -> np.ndarray:
+def request_gaps(layer, wts, dims, prompt, served, quant=None) -> np.ndarray:
     """Gaps of the served tokens of one request, teacher-forced: the
     reference reads the prompt and every served token but the last.
     With ``quant`` it returns the gaps of the tokens that the lower
@@ -167,8 +140,8 @@ def request_gaps(wts, dims, prompt, served, quant=None) -> np.ndarray:
     served = np.asarray(served, np.int64)
     seq = np.concatenate([np.asarray(prompt, np.int64), served[:-1]])
     pos = served_positions(len(prompt), len(served))
-    ref = logits_at(wts, dims, seq, pos)
+    ref = logits_at(layer, wts, dims, seq, pos)
     if quant is None:
         return gaps(ref, served)
-    low = logits_at(wts, dims, seq, pos, quant)
+    low = logits_at(layer, wts, dims, seq, pos, quant)
     return gaps(ref, np.asarray(jnp.argmax(low, axis=-1)))

@@ -73,10 +73,12 @@ def least_time(flops: float, nbytes: float, pk: Peaks):
 
 
 # ------------------------------------------------------------- whole steps
+# The block's ``Dims`` (bench/blocks/<block>.py) gives the counts that
+# depend on its layers: weights per token, and attention over a context.
 def token_flops(dims) -> int:
     """Matrix-product operations per token through every layer (no
     attention scores, no head)."""
-    return 2 * dims.layers * dims.layer_matmul_params()
+    return 2 * dims.token_matmul_params()
 
 
 def head_flops(dims) -> int:
@@ -88,16 +90,15 @@ def decode_step_flops(dims, lengths: Sequence[int]) -> int:
     the step's append): layers, head and attention over the context."""
     per_row = token_flops(dims) + head_flops(dims)
     return (len(lengths) * per_row
-            + dims.layers * paged_attention_flops(dims, lengths))
+            + sum(dims.context_flops(n) for n in lengths))
 
 
 def prefill_flops(dims, prompt_lengths: Sequence[int]) -> int:
     """Prefill of real prompt tokens: layers for every token, causal
-    attention (position p reads p + 1 keys) and the head at the last."""
+    attention and the head at the last."""
     total = 0
     for n in prompt_lengths:
         n = int(n)
         total += n * token_flops(dims) + head_flops(dims)
-        total += dims.layers * 4 * dims.heads * dims.head_dim \
-            * n * (n + 1) // 2
+        total += dims.prompt_context_flops(n)
     return total

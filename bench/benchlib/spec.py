@@ -2,7 +2,11 @@
 configuration; each configuration, traffic mix and per-layer metric is a
 file of its own that is found here by its name.
 
-- ``bench/configs/<config>.json``: the configuration as it is run;
+- ``bench/configs/<config>.json``: the configuration as it is run; its
+  ``"block"`` names the block module;
+- ``bench/blocks/<block>.py``: the block's sizes and yardstick counts,
+  its program mapping and its reference (the contract is written in
+  ``bench/blocks/dense_gqa.py``);
 - ``bench/traffic/<mix>.json``: the generator kind and its parameters;
 - ``bench/metrics/<metric>.py``: a reader ``read(rec)`` that returns the
   metric's value from a run's record, or None where it finds nothing. A
@@ -14,6 +18,7 @@ from __future__ import annotations
 
 import importlib.util
 import json
+import sys
 from pathlib import Path
 from typing import Callable, Dict, List
 
@@ -65,12 +70,38 @@ def load_reader(name: str, bench_dir: Path = BENCH_DIR) -> Callable:
     """``read`` of the metric's file (``reader_path``). Metric names may
     hold dots, so the module is loaded from its path, not imported by
     name."""
-    path = reader_path(name, bench_dir)
-    spec = importlib.util.spec_from_file_location(
-        "bench_metric_" + name.replace(".", "_").replace("-", "_"), path)
+    return _module(reader_path(name, bench_dir), "bench_metric_" + name).read
+
+
+def _module(path: Path, name: str):
+    """The module of the file ``path``, run under ``name`` (dots and dashes
+    made underscores) and entered in ``sys.modules`` first, as a dataclass
+    that the file defines looks its module up there."""
+    name = name.replace(".", "_").replace("-", "_")
+    spec = importlib.util.spec_from_file_location(name, path)
     mod = importlib.util.module_from_spec(spec)
+    sys.modules[name] = mod
     spec.loader.exec_module(mod)
-    return mod.read
+    return mod
+
+
+def load_block(name: str, bench_dir: Path = BENCH_DIR):
+    """The block module ``bench/blocks/<name>.py``, loaded from its path as
+    a reader is. There is no default block."""
+    path = Path(bench_dir) / "blocks" / f"{name}.py"
+    if not path.exists():
+        have = sorted(p.stem for p in path.parent.glob("*.py"))
+        raise FileNotFoundError(f"no block {name!r}: no file {path} (the "
+                                f"blocks of bench/blocks/ here: {have})")
+    return _module(path, "bench_block_" + name)
+
+
+def block_of(cfile: Dict, bench_dir: Path = BENCH_DIR):
+    """The block module that configuration file ``cfile`` names."""
+    if "block" not in cfile:
+        raise KeyError("the configuration names no \"block\": give it the "
+                       "name of a module of bench/blocks/")
+    return load_block(cfile["block"], bench_dir)
 
 
 def end_to_end_for(bench: Dict, cell: str) -> List[Dict]:

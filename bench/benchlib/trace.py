@@ -18,6 +18,7 @@ from __future__ import annotations
 import dataclasses
 import glob
 import os
+import re
 from typing import Dict, List, Optional, Sequence, Tuple
 
 Event = Tuple[str, int, int]          # (name, start_ns, duration_ns)
@@ -81,15 +82,24 @@ def load(path: str) -> Trace:
 
 
 KERNEL_TARGET = 'custom_call_target="tpu_custom_call"'
+KERNEL_MARK = " tpu_custom_call"
 
 
 def _op_label(ev) -> str:
     """Short name of a device operation; a Pallas kernel (a Mosaic custom
-    call, which the trace does not name) is marked ``tpu_custom_call``."""
+    call, its instruction named after the ``pallas_call``'s ``name``) is
+    marked ``tpu_custom_call``: ``%paged_attention.10 tpu_custom_call``."""
     label = op_name(ev.name)
     if KERNEL_TARGET in ev.name:
-        return f"{label} tpu_custom_call"
+        return label + KERNEL_MARK
     return label
+
+
+def kernel_calls(events: Sequence[Event], name: str) -> List[Event]:
+    """The calls of the Pallas kernel ``name``: custom calls whose
+    instruction is ``%<name>`` or ``%<name>.<n>``, and no other kernel."""
+    pat = re.compile(rf"%?{re.escape(name)}(\.\d+)?{KERNEL_MARK}")
+    return [ev for ev in events if pat.fullmatch(ev[0])]
 
 
 def self_time(events: Sequence[Event]) -> List[Event]:

@@ -32,10 +32,10 @@ def main(argv=None) -> int:
     for seed in (int(s) for s in args.seeds.split(",")):
         seen = {}
 
-        def inspect(sample, params, dims, cfile, seed=seed):
+        def inspect(sample, params, block, dims, cfile, seed=seed):
             if seed in control:
                 seen["control"] = run.check_outputs(
-                    sample, params, dims, cfile["eos_token_id"],
+                    block, sample, params, dims, cfile["eos_token_id"],
                     cfile["check"]["logit_gap"], quant="control")
         res = run.run_cell(bench, cell, seed, args.seconds, False, dev,
                            inspect=inspect)

@@ -8,7 +8,8 @@ device, resolves the serving plan, warms every prefill shape the traffic
 can reach, starts the traffic, opens the window once the batch is at its
 steady occupancy, measures for ``--seconds``, serves what came due in the
 window to its end, and then checks a sample of the served tokens against
-the plain float32 reference (``benchlib.reference``).
+the plain float32 reference of the configuration's block
+(``bench/blocks/<block>.py``, named by the file's ``"block"``).
 
 The last line of stdout is one JSON object: ``correct``, ``attempted``,
 ``failed``, ``metrics`` (the cell's end-to-end metrics, or with
@@ -149,12 +150,13 @@ def verdict(checks) -> bool:
     return all(v <= lim for v, lim in checks.values())
 
 
-def check_outputs(sample, params, dims, eos: int, limit: float, quant=None):
+def check_outputs(block, sample, params, dims, eos: int, limit: float,
+                  quant=None):
     """Numbers compared, each (value, limit): the widest logit gap of a
-    served token below the reference's best, answers that stop short of
-    their budget without an end token, and tokens outside the vocabulary."""
-    from benchlib import model, reference
-    wts = model.plain_weights(params)
+    served token below the block's reference's best, answers that stop
+    short of their budget without an end token, and tokens outside the
+    vocabulary."""
+    wts = block.plain_weights(params)
     widest, short, oov, n = 0.0, 0, 0, 0
     for r in sample:
         out = np.asarray(r.out, np.int64)
@@ -163,7 +165,7 @@ def check_outputs(sample, params, dims, eos: int, limit: float, quant=None):
             short += 1
         if len(out) == 0 or oov:
             continue
-        g = reference.request_gaps(wts, dims, r.prompt, out, quant)
+        g = block.request_gaps(wts, dims, r.prompt, out, quant)
         widest = max(widest, float(g.max()))
         n += len(out)
     return {"logit_gap": (widest, limit), "short_answers": (short, 0),
@@ -171,21 +173,24 @@ def check_outputs(sample, params, dims, eos: int, limit: float, quant=None):
 
 
 def run_cell(bench, cell, seed: int, seconds: float, trace: bool, dev, *,
-             cfile=None, tfile=None, patch=None, inspect=None):
+             cfile=None, tfile=None, patch=None, inspect=None,
+             bench_dir=BENCH_DIR):
     """One run of ``cell``; returns the result object. ``cfile``/``tfile``
-    replace the cell's configuration and traffic files; ``patch(sched)``
-    may alter the scheduler and
-    ``inspect(sample, params, dims, cfile)`` sees the checked requests
-    (tests and the control's calibration)."""
+    replace the cell's configuration and traffic files, ``bench_dir`` the
+    directory that holds its traffic, blocks and metric readers;
+    ``patch(sched)`` may alter the scheduler and
+    ``inspect(sample, params, block, dims, cfile)`` sees the checked
+    requests (tests and the control's calibration)."""
     import jax
     from benchlib import model, readers, serve, trace as trace_lib
     from repro.serve.guard import GuardConfig
     from repro.serve.scheduler import ContinuousBatchingScheduler
 
     cfile = cfile or spec.load_config(bench, cell["config"])
-    tfile = tfile or spec.load_traffic(cell["traffic"])
-    dims = model.Dims.from_file(cfile)
-    arch = model.program_config(cfile)
+    tfile = tfile or spec.load_traffic(cell["traffic"], bench_dir)
+    block = spec.block_of(cfile, bench_dir)
+    dims = block.Dims.from_file(cfile)
+    arch = block.program_config(cfile)
     clock = CompileClock()
     params = jax.block_until_ready(model.make_params(
         arch, seed_key(seed), cfile["initializer_range"]))
@@ -373,7 +378,8 @@ def run_cell(bench, cell, seed: int, seconds: float, trace: bool, dev, *,
         / (close_t - open_t),
     }
     for name in names:
-        value = e2e[name]() if name in e2e else spec.load_reader(name)(rec)
+        value = e2e[name]() if name in e2e \
+            else spec.load_reader(name, bench_dir)(rec)
         if value is not None:
             metrics[name] = {"value": float(value), "unit": units[name]}
     for note in rec.notes:
@@ -385,10 +391,10 @@ def run_cell(bench, cell, seed: int, seconds: float, trace: bool, dev, *,
     log(f"reference: {len(sample)} requests, "
         f"{sum(len(r.out) for r in sample)} served tokens")
     if inspect is not None:
-        inspect(sample, params, dims, cfile)
+        inspect(sample, params, block, dims, cfile)
     t_ref = time.monotonic()
-    checks = check_outputs(sample, params, dims, cfile["eos_token_id"],
-                           cfile["check"]["logit_gap"])
+    checks = check_outputs(block, sample, params, dims,
+                           cfile["eos_token_id"], cfile["check"]["logit_gap"])
     # a request refused, cut by the guard or never answered is a wrong answer
     checks["failed"] = (failed, 0)
     log(f"reference seconds: {time.monotonic() - t_ref:.3f}")

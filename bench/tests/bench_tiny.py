@@ -14,7 +14,8 @@ if str(BENCH) not in sys.path:
     sys.path.insert(0, str(BENCH))
 
 CONFIG = {
-    "registry": "qwen2.5-3b", "source": "toy widths of the qwen2.5 block",
+    "registry": "qwen2.5-3b", "block": "dense_gqa",
+    "source": "toy widths of the qwen2.5 block",
     "num_hidden_layers": 2, "hidden_size": 64, "num_attention_heads": 4,
     "num_key_value_heads": 2, "head_dim": 16, "intermediate_size": 128,
     "vocab_size": 503, "rope_theta": 10000.0, "rms_norm_eps": 1e-6,
@@ -45,10 +46,11 @@ BENCH_SPEC = {
 CELL = {"name": "tiny", "config": "tiny", "traffic": "tiny", "chips": 1}
 
 
-def run(seed, patch=None, seconds=1.5, cfile=None, inspect=None):
+def run(seed, patch=None, seconds=1.5, cfile=None, inspect=None,
+        bench_dir=BENCH):
     import jax
     import run as bench_run
     return bench_run.run_cell(
         BENCH_SPEC, CELL, seed, seconds, False, jax.devices()[0],
         cfile=copy.deepcopy(cfile or CONFIG), tfile=copy.deepcopy(TRAFFIC),
-        patch=patch, inspect=inspect)
+        patch=patch, inspect=inspect, bench_dir=bench_dir)

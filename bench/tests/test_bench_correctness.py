@@ -87,12 +87,12 @@ def test_control_is_not_correct():
     import run as bench_run
     seen = {}
 
-    def inspect(sample, params, dims, cfile):
+    def inspect(sample, params, block, dims, cfile):
         seen["program"] = bench_run.check_outputs(
-            sample, params, dims, cfile["eos_token_id"],
+            block, sample, params, dims, cfile["eos_token_id"],
             cfile["check"]["logit_gap"])
         seen["control"] = bench_run.check_outputs(
-            sample, params, dims, cfile["eos_token_id"],
+            block, sample, params, dims, cfile["eos_token_id"],
             cfile["check"]["logit_gap"], quant="control")
     res = bench_tiny.run(2**35 + 3, inspect=inspect, seconds=2.0)
     assert res["correct"], res["checks"]

@@ -7,7 +7,7 @@ import re
 import pytest
 
 import bench_tiny
-from benchlib import REPO_ROOT, model, spec, traffic
+from benchlib import REPO_ROOT, spec, traffic
 
 BENCH = spec.load_benchmark(REPO_ROOT)
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
@@ -26,8 +26,9 @@ def test_config_loads(name):
     c = spec.load_config(BENCH, entry["name"])
     assert c["source"] == entry["source"]
     assert c["reduced"] == entry["reduced"]
-    dims = model.Dims.from_file(c)
-    arch = model.program_config(c)
+    block = spec.block_of(c)
+    dims = block.Dims.from_file(c)
+    arch = block.program_config(c)
     assert (arch.num_layers, arch.d_model, arch.num_heads, arch.num_kv_heads,
             arch.head_dim, arch.d_ff, arch.vocab_size) == \
         (dims.layers, dims.d, dims.heads, dims.kv_heads, dims.head_dim,

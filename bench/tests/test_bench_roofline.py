@@ -4,14 +4,15 @@ import json
 import pytest
 
 import bench_tiny
-from benchlib import model, roofline
+from benchlib import roofline, spec
 
 CONFIGS = bench_tiny.BENCH / "configs"
 
 
 def _dims(name):
     with open(CONFIGS / f"{name}.json") as f:
-        return model.Dims.from_file(json.load(f))
+        c = json.load(f)
+    return spec.block_of(c).Dims.from_file(c)
 
 
 def test_peaks_by_device_kind():

@@ -85,3 +85,31 @@ def test_inside_keeps_the_events_within_a_program():
     kernels = [("k", 1 * MS, MS), ("k", 5 * MS, MS), ("k", 8 * MS, MS)]
     programs = [("jit_chunk(1)", 0, 4 * MS), ("jit_chunk(1)", 7 * MS, 3 * MS)]
     assert [s for _, s, _ in T.inside(kernels, programs)] == [MS, 8 * MS]
+
+
+def test_roofline_counts_only_the_paged_kernel_on_paged_layers():
+    """A second custom call inside ``jit_chunk`` is not the paged kernel,
+    and each step's least time is counted once per paged layer."""
+    import types
+    from benchlib import readers, roofline, serve
+    dims = types.SimpleNamespace(layers=4, paged_layers=1, heads=4,
+                                 kv_heads=2, head_dim=16)
+    ops = [("%paged_attention.10 tpu_custom_call", 1 * MS, MS),
+           ("%local_attention.3 tpu_custom_call", 2 * MS, MS),
+           ("%fusion.7", 3 * MS, MS // 2)]
+    tr = T.Trace(ops=ops, modules=[("jit_chunk(3)", 0, 4 * MS)],
+                 spans=[("bench.window", 0, 10 * MS)])
+    step = [100, 200]
+    b = serve.Boundary(t0=1.0, t1=2.0, tokens=2, status={}, stats={},
+                       admitted=[], steps=[step])
+    rec = readers.Record(window=(0.0, 10.0), end=10.0, reqs=[],
+                         boundaries=[b], counted=[], dims=dims, rows=2,
+                         page_size=16, kv_bytes=1, kind="TPU v5 lite",
+                         trace=tr, trace_window=(0, 10 * MS))
+    least, _ = roofline.least_time(
+        roofline.paged_attention_flops(dims, step),
+        roofline.paged_attention_bytes(dims, step, 16, 1),
+        roofline.peaks("TPU v5 lite"))
+    got = readers.paged_attention_roofline(rec)
+    assert got == pytest.approx(100.0 * least / 1e-3)
+    assert T.kernel_calls(ops, "paged_attention") == ops[:1]
